@@ -34,6 +34,8 @@ def main(argv=None) -> None:
                     help="machine-readable results path ('' to disable)")
     args = ap.parse_args(argv)
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_analysis_latency, bench_autonomic_e2e,
                             bench_change_detector, bench_classifiers,
                             bench_clustering, bench_costmodel,

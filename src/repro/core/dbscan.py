@@ -35,8 +35,7 @@ def pairwise_sq_dists(x, impl: str = "auto"):
     if impl in ("pallas", "pallas_interpret", "legacy"):
         from repro.kernels import pairdist
         want = "pallas_interpret" if impl == "legacy" else impl
-        return pairdist.pairdist(x,
-                                 interpret=dispatch.resolve(want) != "pallas")
+        return pairdist.pairdist(x, interpret=dispatch.interpret_mode(want))
     x = x.astype(jnp.float32)
     n2 = jnp.sum(x * x, axis=1)
     d2 = n2[:, None] + n2[None, :] - 2.0 * (x @ x.T)

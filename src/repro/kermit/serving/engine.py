@@ -218,14 +218,19 @@ class ServeEngine:
         jax.block_until_ready(logits)
         prefill_s = time.perf_counter() - t0
 
-        tokens = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+        # greedy over the real vocabulary: the embedding's padding rows
+        # (vocab_padded > vocab) are never a token
+        vocab = self.cfg.vocab
+        tokens = jnp.argmax(logits[:, -1, :vocab], -1)[:, None].astype(
+            jnp.int32)
         out = [tokens]
         t0 = time.perf_counter()
         for i in range(steps):
             step_batch = {"tokens": tokens,
                           "pos": jnp.asarray(prompt_len + i, jnp.int32)}
             logits, cache = decode(self.params, cache, step_batch)
-            tokens = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+            tokens = jnp.argmax(logits[:, -1, :vocab], -1)[:, None].astype(
+                jnp.int32)
             out.append(tokens)
         jax.block_until_ready(tokens)
         decode_s = time.perf_counter() - t0

@@ -1,19 +1,21 @@
-"""Backend detection and kernel-implementation dispatch.
+"""Backend detection and kernel-implementation dispatch — the one rule.
 
-Every Pallas kernel in this package has three execution strategies:
+Every Pallas kernel in this package has up to three execution strategies:
 
-* ``"pallas"``           — compiled ``pl.pallas_call`` (TPU/GPU lowering)
+* ``"pallas"``           — compiled ``pl.pallas_call`` (TPU only)
 * ``"pallas_interpret"`` — the same kernel through the Pallas interpreter
                            (CPU-correct but slow; debugging / parity only)
 * ``"xla"``              — a tiled pure-jnp formulation compiled by XLA
                            (the CPU fast path; memory profile matches the
                            Pallas kernel — no (N, N) float32 in host RAM)
 
-``resolve("auto")`` picks the fastest strategy for the current backend:
-compiled Pallas on TPU/GPU, XLA tiles on CPU.  Interpret mode is never
-selected implicitly — it must be requested by name (or via the
-``REPRO_KERNEL_IMPL`` environment variable), which replaces the seed
-behaviour of running ``interpret=True`` unconditionally.
+``resolve("auto")`` picks compiled Pallas on a TPU and XLA tiles
+elsewhere.  Interpret mode is never selected implicitly — it must be
+requested by name (or via the ``REPRO_KERNEL_IMPL`` environment variable).
+Asking for compiled ``"pallas"`` off a TPU is an error, not a quiet
+downgrade.  ``interpret_mode`` answers the same question for call sites
+that only have a Pallas kernel (flash attention, the SSD scan, dense
+pairdist).
 """
 from __future__ import annotations
 
@@ -27,35 +29,40 @@ _ENV_VAR = "REPRO_KERNEL_IMPL"
 
 
 def backend() -> str:
-    """The active JAX backend: "cpu", "gpu" or "tpu"."""
+    """The active JAX backend: "cpu" or "tpu"."""
     return jax.default_backend()
-
-
-def supports_compiled_pallas() -> bool:
-    """True when ``pl.pallas_call(..., interpret=False)`` can lower."""
-    return backend() in ("tpu", "gpu")
 
 
 def resolve(impl: str = "auto") -> str:
     """Map a requested implementation to a concrete one.
 
     "auto" honours ``REPRO_KERNEL_IMPL`` if set, then picks compiled
-    Pallas on TPU/GPU and the XLA tile path on CPU.  Explicit names pass
-    through (with "pallas" downgraded to interpret mode off-accelerator
-    so parity tests run everywhere).
+    Pallas on TPU and the XLA tile path elsewhere.  Explicit names pass
+    through; "pallas" raises when the backend is not a TPU.
     """
     if impl in ("auto", None):
         impl = os.environ.get(_ENV_VAR, "").strip().lower() or "auto"
     if impl == "auto":
-        return "pallas" if supports_compiled_pallas() else "xla"
+        return "pallas" if backend() == "tpu" else "xla"
     if impl not in IMPLS:
         raise ValueError(f"unknown kernel impl {impl!r}; expected one of "
                          f"{('auto',) + IMPLS}")
-    if impl == "pallas" and not supports_compiled_pallas():
-        return "pallas_interpret"
+    if impl == "pallas" and backend() != "tpu":
+        raise RuntimeError(
+            f"compiled Pallas needs a TPU, but the JAX backend is "
+            f"{backend()!r}; ask for 'pallas_interpret' to run the kernel "
+            f"in the interpreter")
     return impl
 
 
 def interpret_mode(impl: str = "auto") -> bool:
-    """Whether a ``pl.pallas_call`` for this request must interpret."""
-    return resolve(impl) != "pallas"
+    """Whether a ``pl.pallas_call`` for this request runs in the
+    interpreter.  Raises when the request resolves to a non-Pallas
+    strategy, which a Pallas-only call site cannot honour."""
+    resolved = resolve(impl)
+    if resolved not in ("pallas", "pallas_interpret"):
+        raise RuntimeError(
+            f"kernel impl {impl!r} resolves to {resolved!r} on backend "
+            f"{backend()!r}, but this call site only has a Pallas kernel; "
+            f"pass interpret=True or set {_ENV_VAR}=pallas_interpret")
+    return resolved == "pallas_interpret"

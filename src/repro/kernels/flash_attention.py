@@ -20,13 +20,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from repro.kernels import dispatch
 
 NEG = -1e30
 
@@ -125,10 +121,10 @@ def _flash_fwd(q, k, v, kv_len=None, *, causal=True, window=0, softcap=0.0,
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sqp, d), q.dtype),
         scratch_shapes=[
-            _VMEM((bq, 1), jnp.float32),
-            _VMEM((bq, 1), jnp.float32),
-            _VMEM((bq, d), jnp.float32),
-        ] if _VMEM is not None else None,
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+        ],
         interpret=interpret,
     )(qT, kT, vT)
     return out.transpose(0, 2, 1, 3)[:, :Sq]
@@ -165,9 +161,11 @@ flash_attention_core.defvjp(_fwd, _bwd)
 
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=0.0,
                     q_pos=None, kv_pos=None, interpret=None):
-    """Public entry. On CPU (no TPU backend) defaults to interpret mode."""
+    """Public entry.  ``interpret=None`` asks ``dispatch.interpret_mode``:
+    compiled on a TPU, an error elsewhere unless interpretation is asked
+    for."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret_mode()
     w = int(window) if window is not None and not hasattr(window, "shape") \
         else 0
     return flash_attention_core(q, k, v, causal, w, float(softcap),

@@ -1,6 +1,6 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Backend dispatch lives in ``kernels.dispatch``: compiled Mosaic on TPU/GPU,
+Backend dispatch lives in ``kernels.dispatch``: compiled Mosaic on TPU,
 tiled XLA twins on CPU, interpret mode only on explicit request (parity
 tests, ``REPRO_KERNEL_IMPL=pallas_interpret``).
 """

@@ -15,7 +15,7 @@ is O(N²F) and reruns at every off-line analysis interval.  Two kernels:
                             block (8 columns per byte), an 8×/32× smaller
                             HBM footprint than bool/float32.
 
-Backend selection lives in ``kernels.dispatch``: compiled Pallas on TPU/GPU,
+Backend selection lives in ``kernels.dispatch``: compiled Pallas on TPU,
 a tiled pure-jnp twin (identical arithmetic, identical packing) on CPU, and
 interpret mode only on explicit request.
 
@@ -24,6 +24,7 @@ ref.py oracle: ``ref_pairdist`` below (pure jnp).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -91,9 +92,17 @@ def pairdist(x, *, block: int = 128, interpret: bool | None = None):
 # -- fused streaming ε-neighbourhood kernel -----------------------------------
 #
 # Bit layout: adjacency column j lives in byte j // 8, bit j % 8 (LSB first).
-# pack/unpack below are the single source of truth for that layout; the XLA
-# twin and the Pallas kernel both go through _pack_bits so the outputs are
-# bit-identical across backends.
+# ``unpack_bits`` is the single source of truth for that layout.  The XLA
+# twin packs with ``_pack_bits`` (a reshape and a shift-sum); the Pallas
+# kernel packs with a matmul against ``_pack_matrix``, because Mosaic cannot
+# split the lane axis in-kernel.  Both give the same integers, so the two
+# outputs are bit-identical.
+
+# One lane-dense row of packed bytes (128 lanes) covers 1024 columns; wider
+# problems walk column tiles of that size so every packed block is a whole
+# row or exactly 128 lanes wide (the TPU's (8, 128) tiling).
+_TILE_COLS = 8 * 128
+
 
 def _bit_positions():
     # built inline (not a module constant) so Pallas kernels don't capture it
@@ -114,11 +123,32 @@ def unpack_bits(packed, n_cols: int | None = None):
     return out if n_cols is None else out[..., :n_cols]
 
 
-def _nbr_kernel(x_ref, y_ref, cnt_ref, adj_ref, *, eps_sq, n, bn,
-                accumulate):
+def _pack_matrix(bn: int):
+    """(bn, bn // 8) bf16 with entry [j, j // 8] = 2 ** (j % 8): a 0/1 row
+    times this matrix is its packed bytes.  Every product and partial sum is
+    an integer below 256, exact from bf16 inputs with f32 accumulation."""
+    j = jnp.arange(bn)
+    own_byte = (j // 8)[:, None] == jnp.arange(bn // 8)[None, :]
+    return jnp.where(own_byte, (1 << (j % 8))[:, None],
+                     0).astype(jnp.bfloat16)
+
+
+def _tiling(n: int, block: int) -> tuple[int, int, int]:
+    """(bm, bn, npad): row tile, column tile and padded size, shared by the
+    kernel and its XLA twin so both emit the same (npad, npad // 8) array."""
+    bm = min(block, max(8, -(-n // 8) * 8))
+    bm = max(8, bm - bm % 8)
+    npad = -(-n // bm) * bm
+    if npad <= _TILE_COLS:
+        return bm, npad, npad
+    step = math.lcm(bm, _TILE_COLS)
+    return bm, _TILE_COLS, -(-n // step) * step
+
+
+def _nbr_kernel(x_ref, y_ref, pack_ref, cnt_ref, adj_ref, *, eps_sq, n, bn):
     """One (bm, bn) tile: threshold at ε² in registers, emit the packed
-    adjacency block (and, where the grid is sequential, accumulate per-row
-    counts over the j axis).  The (bm, bn) float32 tile never leaves VMEM."""
+    adjacency block and accumulate per-row counts over the sequential j
+    axis.  The (bm, bn) float32 tile never leaves VMEM."""
     j = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)          # (bm, F)
     y = y_ref[...].astype(jnp.float32)          # (bn, F)
@@ -131,22 +161,15 @@ def _nbr_kernel(x_ref, y_ref, cnt_ref, adj_ref, *, eps_sq, n, bn,
     col = j * bn + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
     adj = (d2 <= eps_sq) & (col < n)
 
-    if accumulate:
-        @pl.when(j == 0)
-        def _():
-            cnt_ref[...] = jnp.zeros_like(cnt_ref)
+    @pl.when(j == 0)
+    def _():
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-        cnt_ref[...] += jnp.sum(adj, axis=1).astype(jnp.int32)
-    else:
-        cnt_ref[...] = jnp.sum(adj, axis=1).astype(jnp.int32)
-    adj_ref[...] = _pack_bits(adj)
-
-
-def _sequential_grid(interpret: bool) -> bool:
-    """Output revisiting (the j-axis count accumulation) is only sound where
-    grid cells run in order: the Pallas interpreter and TPU's sequential
-    grid.  GPU grid programs are parallel — accumulate outside the kernel."""
-    return interpret or dispatch.backend() == "tpu"
+    cnt_ref[...] += jnp.sum(adj.astype(jnp.int32), axis=1, keepdims=True)
+    bits = jnp.where(adj, 1.0, 0.0).astype(jnp.bfloat16)
+    packed = jax.lax.dot(bits, pack_ref[...],
+                         preferred_element_type=jnp.float32)
+    adj_ref[...] = packed.astype(jnp.int32).astype(jnp.uint8)
 
 
 @functools.partial(jax.jit,
@@ -154,42 +177,28 @@ def _sequential_grid(interpret: bool) -> bool:
 def _neighbor_adjacency_pallas(x, *, eps_sq: float, block: int,
                                interpret: bool):
     n, f = x.shape
-    bm = min(block, max(8, -(-n // 8) * 8))
-    bm = max(8, bm - bm % 8)
-    npad = (-n) % bm
-    if npad:
-        x = jnp.pad(x, ((0, npad), (0, 0)))
-    np_ = x.shape[0]
-    grid = (np_ // bm, np_ // bm)
-    accumulate = _sequential_grid(interpret)
-    kern = functools.partial(_nbr_kernel, eps_sq=eps_sq, n=n, bn=bm,
-                             accumulate=accumulate)
+    bm, bn, np_ = _tiling(n, block)
+    x = jnp.pad(x, ((0, np_ - n), (0, 0)))
+    kern = functools.partial(_nbr_kernel, eps_sq=eps_sq, n=n, bn=bn)
     counts, packed = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(np_ // bm, np_ // bn),
         in_specs=[
             pl.BlockSpec((bm, f), lambda i, j: (i, 0)),
-            pl.BlockSpec((bm, f), lambda i, j: (j, 0)),
+            pl.BlockSpec((bn, f), lambda i, j: (j, 0)),
+            pl.BlockSpec((bn, bn // 8), lambda i, j: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bm, bm // 8), lambda i, j: (i, j)),
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((bm, bn // 8), lambda i, j: (i, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((np_,), jnp.int32),
+            jax.ShapeDtypeStruct((np_, 1), jnp.int32),
             jax.ShapeDtypeStruct((np_, np_ // 8), jnp.uint8),
         ],
         interpret=interpret,
-    )(x, x)
-    if not accumulate:
-        # parallel grid: the kernel's counts output only holds the last
-        # j-tile; recount from the packed adjacency (one XLA popcount pass)
-        def strip(pb):
-            return jnp.sum(unpack_bits(pb), axis=1).astype(jnp.int32)
-
-        counts = jax.lax.map(
-            strip, packed.reshape(np_ // bm, bm, np_ // 8)).reshape(np_)
-    return counts, packed
+    )(x, x, _pack_matrix(bn))
+    return counts.reshape(np_), packed
 
 
 @functools.partial(jax.jit, static_argnames=("eps_sq", "block"))
@@ -198,13 +207,8 @@ def _neighbor_adjacency_xla(x, *, eps_sq: float, block: int):
     thresholding and bit packing, compiled by XLA.  Peak memory is one
     (bm, Npad) strip, never the full (N, N) matrix."""
     n, f = x.shape
-    x = x.astype(jnp.float32)
-    bm = min(block, max(8, -(-n // 8) * 8))
-    bm = max(8, bm - bm % 8)
-    npad = (-n) % bm
-    if npad:
-        x = jnp.pad(x, ((0, npad), (0, 0)))
-    np_ = x.shape[0]
+    bm, _, np_ = _tiling(n, block)
+    x = jnp.pad(x.astype(jnp.float32), ((0, np_ - n), (0, 0)))
     yy = jnp.sum(x * x, axis=1)
     col_ok = jnp.arange(np_) < n
 

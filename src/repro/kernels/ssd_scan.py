@@ -1,12 +1,19 @@
 """Pallas TPU kernel: Mamba2 SSD chunked scan.
 
-Grid = (batch, chunks); the chunk axis is innermost/sequential, so the carried
-SSM state (H, N, P) lives in f32 VMEM scratch across chunk iterations — the
-inter-chunk recurrence never round-trips to HBM (on GPU this is the kernel the
-paper's SSD algorithm fuses; on TPU the win is identical: the state stays in
-VMEM and each chunk's intra-chunk quadratic work feeds the MXU).
+Grid = (batch, head_blocks, chunks); the chunk axis is innermost/sequential,
+so the carried SSM state (hb, N, P) of a head block lives in f32 VMEM
+scratch across chunk iterations — the inter-chunk recurrence never
+round-trips to HBM (on GPU this is the kernel the paper's SSD algorithm
+fuses; on TPU the win is identical: the state stays in VMEM and each chunk's
+intra-chunk quadratic work feeds the MXU).
 
-Per chunk (length Q): decay cumsum, intra-chunk (C·Bᵀ ⊙ L) x, state read
+Blocking heads bounds VMEM: each program holds (Q, Q) temporaries for one
+head at a time instead of (H, Q, Q) for all of them.  The within-chunk
+cumulative log-decay is computed by XLA in the wrapper, exactly as the
+reference does (Mosaic has no cumsum); everything in the kernel is a 2-D
+matmul, an elementwise op or a reduction.
+
+Per chunk (length Q) and head: intra-chunk (C·Bᵀ ⊙ L) x, state read
 C·S_prev, state update S = tot·S_prev + Σ decay·dt·B⊗x.
 
 ref oracle: repro.models.mamba2.ssd_chunked.
@@ -18,62 +25,67 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from repro.kernels import dispatch
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, sfin_ref, s_scr, *,
-            nc, Q, H, P, G, N):
-    ci = pl.program_id(1)
+def _dot(a, b):
+    return jax.lax.dot(a, b, precision=_HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
+def _kernel(x_ref, dt_ref, cum_ref, bt_ref, c_ref, y_ref, sfin_ref, s_scr,
+            *, nc, hb):
+    ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    x = x_ref[0].astype(jnp.float32)        # (Q, H, P)
-    dt = dt_ref[0].astype(jnp.float32)      # (Q, H)
-    A = a_ref[...].astype(jnp.float32)      # (H,)
-    Bm = b_ref[0].astype(jnp.float32)       # (Q, G, N)
-    Cm = c_ref[0].astype(jnp.float32)       # (Q, G, N)
-    r = H // G
+    Bt = bt_ref[0, 0].astype(jnp.float32)   # (N, Q) this head block's group
+    Cm = c_ref[0, 0].astype(jnp.float32)    # (Q, N)
+    Q = Cm.shape[0]
+    CB = _dot(Cm, Bt)                       # (Q, Q): C_i · B_j
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    causal = row >= col
+    diag = row == col
+    is_last = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1) == Q - 1
 
-    a = dt * A                              # (Q, H) negative increments
-    cum = jnp.cumsum(a, axis=0)             # (Q, H)
+    for h in range(hb):
+        x = x_ref[0, h].astype(jnp.float32)             # (Q, P)
+        dt = dt_ref[0, pl.ds(h, 1), :]                  # (1, Q)
+        cum = cum_ref[0, pl.ds(h, 1), :]                # (1, Q)
+        # the same values as a column, via the diagonal (no in-kernel
+        # transpose of a single row)
+        cum_col = jnp.sum(jnp.where(diag, cum, 0.0), axis=1, keepdims=True)
+        last = jnp.sum(jnp.where(is_last, cum, 0.0), axis=1, keepdims=True)
 
-    # intra-chunk: scores[h,i,j] = (C_i·B_j) exp(cum_i - cum_j) dt_j, i>=j
-    CB = jnp.einsum("igN,jgN->gij", Cm, Bm)
-    CB = jnp.repeat(CB, r, axis=0)          # (H, Q, Q)
-    diff = cum.T[:, :, None] - cum.T[:, None, :]
-    tril = jnp.tril(jnp.ones((Q, Q), jnp.bool_))
-    Lm = jnp.exp(jnp.where(tril[None], diff, -1e30))  # mask pre-exp (no inf)
-    scores = CB * Lm * dt.T[:, None, :]
-    y_intra = jnp.einsum("hij,jhp->ihp", scores, x)
-
-    # inter-chunk: read previous state
-    s_prev = s_scr[...]                     # (H, N, P)
-    Ch = jnp.repeat(Cm, r, axis=1).reshape(Q, H, N) if G == 1 else \
-        jnp.repeat(Cm[:, :, None, :], r, axis=2).reshape(Q, H, N)
-    dec_start = jnp.exp(cum)                # (Q, H)
-    y_inter = jnp.einsum("ih,ihn,hnp->ihp", dec_start, Ch, s_prev)
-
-    y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
-
-    # state update
-    Bh = jnp.repeat(Bm, r, axis=1).reshape(Q, H, N) if G == 1 else \
-        jnp.repeat(Bm[:, :, None, :], r, axis=2).reshape(Q, H, N)
-    dec_end = jnp.exp(cum[-1][None, :] - cum)       # (Q, H)
-    S_c = jnp.einsum("jh,jhn,jhp->hnp", dec_end * dt, Bh, x)
-    tot = jnp.exp(cum[-1])                  # (H,)
-    s_scr[...] = s_prev * tot[:, None, None] + S_c
+        # intra-chunk: scores[i, j] = (C_i·B_j) exp(cum_i - cum_j) dt_j, i>=j
+        # (mask before exp: upper-triangle differences are positive)
+        decay = jnp.exp(jnp.where(causal, cum_col - cum, -1e30))
+        y = _dot(CB * decay * dt, x)
+        # inter-chunk: read the state carried in from earlier chunks
+        s_prev = s_scr[h]                               # (N, P)
+        y = y + _dot(jnp.exp(cum_col) * Cm, s_prev)
+        y_ref[0, h] = y.astype(y_ref.dtype)
+        # state update
+        w = jnp.exp(last - cum) * dt                    # (1, Q)
+        s_scr[h] = s_prev * jnp.exp(last) + _dot(Bt * w, x)
 
     @pl.when(ci == nc - 1)
     def _done():
         sfin_ref[0] = s_scr[...]
+
+
+def _head_block(H: int, G: int) -> int:
+    """Heads per program: 8 (the sublane tile) when a group holds a
+    multiple of 8 heads, else one whole group."""
+    r = H // G
+    return 8 if r % 8 == 0 else r
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -85,31 +97,37 @@ def _ssd_fwd(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = False):
     Q = min(chunk, S)
     assert S % Q == 0, (S, Q)
     nc = S // Q
+    hb = _head_block(H, G)
+    r = H // G
 
-    kernel = functools.partial(_kernel, nc=nc, Q=Q, H=H, P=P, G=G, N=N)
+    dt = dt.astype(jnp.float32)
+    cum = jnp.cumsum((dt * A).reshape(Bsz, nc, Q, H), axis=2)
+    cum = cum.reshape(Bsz, S, H).transpose(0, 2, 1)     # (B,H,S)
+
+    kernel = functools.partial(_kernel, nc=nc, hb=hb)
     y, s_fin = pl.pallas_call(
         kernel,
-        grid=(Bsz, nc),
+        grid=(Bsz, H // hb, nc),
         in_specs=[
-            pl.BlockSpec((1, Q, H, P), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, Q, H), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((H,), lambda b, c: (0,)),
-            pl.BlockSpec((1, Q, G, N), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, Q, G, N), lambda b, c: (b, c, 0, 0)),
+            pl.BlockSpec((1, hb, Q, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, hb, Q), lambda b, h, c: (b, h, c)),
+            pl.BlockSpec((1, hb, Q), lambda b, h, c: (b, h, c)),
+            pl.BlockSpec((1, 1, N, Q), lambda b, h, c: (b, h * hb // r, 0, c)),
+            pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, h * hb // r, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, Q, H, P), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, H, N, P), lambda b, c: (b, 0, 0, 0)),
+            pl.BlockSpec((1, hb, Q, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, hb, N, P), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bsz, S, H, P), jnp.float32),
+            jax.ShapeDtypeStruct((Bsz, H, S, P), jnp.float32),
             jax.ShapeDtypeStruct((Bsz, H, N, P), jnp.float32),
         ],
-        scratch_shapes=[_VMEM((H, N, P), jnp.float32)]
-        if _VMEM is not None else None,
+        scratch_shapes=[pltpu.VMEM((hb, N, P), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, Bm, Cm)
-    return y, s_fin
+    )(x.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), cum,
+      Bm.transpose(0, 2, 3, 1), Cm.transpose(0, 2, 1, 3))
+    return y.transpose(0, 2, 1, 3), s_fin
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -137,8 +155,9 @@ ssd_core.defvjp(_fwd, _bwd)
 
 
 def ssd(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool | None = None):
+    """Public entry; ``interpret=None`` asks ``dispatch.interpret_mode``."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = dispatch.interpret_mode()
     Q = min(chunk, x.shape[1])
     while x.shape[1] % Q:
         Q //= 2

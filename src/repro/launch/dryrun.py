@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")   # a CPU-only tool: never take a chip
 
 # Multi-pod dry-run: AOT lower + compile every (arch × shape) cell on the
 # production meshes, record memory/cost/collective analysis for §Roofline.

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")   # a CPU-only tool: never take a chip
 
 # §Perf hillclimb: KERMIT's Explorer searches the runtime-tunable space with
 # the DRY-RUN ROOFLINE as the objective — exactly the paper's plug-in loop,

@@ -11,6 +11,7 @@ device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,9 +26,11 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh needs {n} devices, found {len(devices)} — the dry-run must "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Degenerate 1-device mesh for smoke tests of the sharded code path."""
-    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1],
+                         axis_types=(AxisType.Auto,) * 2)

@@ -11,6 +11,7 @@ import json
 from repro.configs.base import DEFAULT_TUNABLES, reduced
 from repro.configs.registry import ARCHS, get_config
 from repro.kermit.serving.engine import get_engine
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def serve_batch(cfg, batch: int, prompt_len: int, gen: int, tun, seed=0):
@@ -32,6 +33,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not args.full:
         cfg = reduced(cfg)

@@ -24,6 +24,7 @@ from repro.configs.registry import ARCHS, get_config
 from repro.kermit import (KermitConfig, KermitSession, KnowledgeConfig,
                           MonitorConfig)
 from repro.optim.adamw import OptConfig
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault import FailureInjector
 from repro.runtime.loop import Trainer
 
@@ -65,6 +66,7 @@ def main(argv=None):
                     help="inject node failures at these steps")
     ap.add_argument("--tun", nargs="*", default=[], help="tunable k=v")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

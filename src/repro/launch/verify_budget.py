@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ.setdefault("JAX_PLATFORMS", "cpu")   # a CPU-only tool: never take a chip
 
 # Memory-budget post-pass for hillclimb results: walk the search trace in
 # ascending estimated-time order, full-compile each candidate, and keep the

@@ -6,7 +6,8 @@ applied with ``lax.scan`` so the lowered HLO stays compact at 512-way SPMD.
 Attention has two implementations:
   * ``xla``    — chunked (query-blocked) pure-jnp attention; used for the CPU
                  dry-run lowering and as the Pallas oracle.
-  * ``pallas`` — kernels/flash_attention.py (TPU target; interpret=True on CPU).
+  * ``pallas`` — kernels/flash_attention.py (compiled on TPU; see
+                 kernels/dispatch.py).
 """
 from __future__ import annotations
 

@@ -156,6 +156,7 @@ def mamba2_apply(p, x, cfg, *, chunk: int | None = None, impl: str = "xla"):
     if impl == "pallas":
         from repro.kernels import ssd_scan as K
         y, S_last = K.ssd(xs, dt, A, Bm, Cm, chunk=chunk)
+        y = y.astype(xs.dtype)          # f32 kernel output, as ssd_chunked
     else:
         y, S_last = ssd_chunked(xs, dt, A, Bm, Cm, chunk)
     y = y + (p["D_skip"] * xs.astype(jnp.float32).transpose(0, 1, 3, 2)).transpose(0, 1, 3, 2).astype(y.dtype)

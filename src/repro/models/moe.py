@@ -23,18 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.7 style
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
-
 from repro.models import layers as L
 from repro.sharding import rules
 
@@ -123,8 +111,8 @@ def moe_apply(p, x, cfg, *, capacity_factor: float | None = None):
                                   e_offset=j * (E // tp))
             return lax.psum(y, "model")
 
-        y = shard_map(
-            local, mesh,
+        y = jax.shard_map(
+            local, mesh=mesh,
             in_specs=(tok_spec, tok_spec, tok_spec,
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),

@@ -76,6 +76,7 @@ class Trainer:
         self.pipeline = TokenPipeline(cfg, shape, seed=seed,
                                       prefetch=tun.prefetch)
         self.step_num = 0
+        self.infeasible = 0      # candidates measured_objective found OOM
         self._rebuild()
         n_active = sum(int(np.prod(l.shape)) for l in
                        jax.tree_util.tree_leaves(self.state["params"]))
@@ -93,6 +94,10 @@ class Trainer:
     # -- objective for the Explorer (measured trial steps) ---------------------
 
     def measured_objective(self, repeats: int = 1):
+        """Median wall time of ``repeats`` trial steps under a candidate.
+        A candidate that runs out of device memory is infeasible: it costs
+        ``inf`` and counts in ``self.infeasible``.  Any other error (a
+        compile error, a bug) propagates."""
         batch = self.pipeline._make(0)
         batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
 
@@ -109,9 +114,12 @@ class Trainer:
                     s, _ = fn(self.state, batch)
                     jax.block_until_ready(s)
                     ts.append(time.perf_counter() - t0)
-                return float(np.median(ts))
-            except Exception:
+            except jax.errors.JaxRuntimeError as e:
+                if "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                self.infeasible += 1
                 return float("inf")
+            return float(np.median(ts))
         return objective
 
     # -- recovery ---------------------------------------------------------------

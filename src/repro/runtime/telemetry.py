@@ -3,8 +3,9 @@ stream, DESIGN.md §2 mapping table).
 
 Measured live on any backend: step wall-time, tokens/s, host-input wait,
 loss/grad stats. Derived: MFU and HBM proxies from the configured model flops
-and a peak constant (real peaks on TPU; a calibrated CPU constant here so the
-*relative* signal — what KERMIT actually consumes — is meaningful).
+and the device's peak from ``PEAK_FLOPS`` (a published chip peak on TPU; a
+calibrated CPU constant on the host so the *relative* signal — what KERMIT
+actually consumes — is meaningful).
 """
 from __future__ import annotations
 
@@ -19,6 +20,28 @@ import numpy as np
 from repro.core.windows import FEATURES, NUM_FEATURES
 
 _IDX = {f: i for i, f in enumerate(FEATURES)}
+
+# Peak dense FLOP/s of one device, keyed by ``jax.Device.device_kind``.
+PEAK_FLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+    "TPU v5 lite": 197e12,
+    # not a hardware peak: the calibrated CPU-core constant the Monitor's
+    # mfu feature has always used on the host
+    "cpu": 2e11,
+}
+
+
+def peak_flops(device_kind: Optional[str] = None) -> float:
+    """``PEAK_FLOPS`` for ``device_kind`` (default: the first JAX device).
+    A device missing from the table is an error, never a default."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return PEAK_FLOPS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak FLOP/s for device kind {device_kind!r}; "
+                       f"add it to PEAK_FLOPS with its source") from None
 
 
 def percentile(values, q: float) -> float:
@@ -55,12 +78,11 @@ class StepStats:
 class TelemetryEmitter:
     def __init__(self, *, seq_len: int, global_batch: int,
                  model_flops_per_step: float = 0.0,
-                 peak_flops: float = 2e11,      # calibrated CPU-core peak
                  root: str | Path | None = None, agent: str = "agent0"):
         self.seq_len = seq_len
         self.batch = global_batch
         self.mf = model_flops_per_step
-        self.peak = peak_flops
+        self.peak = peak_flops()
         self._prev_loss = None
         self._file = None
         if root is not None:

@@ -19,19 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-
-    def _smap(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _old
-
-    def _smap(f, mesh, in_specs, out_specs):
-        return _old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def stage_split(params_stacked, n_stages: int):
@@ -84,6 +72,7 @@ def gpipe_apply(params_staged, x, stage_fn, *, mesh: Mesh,
 
     pspec = jax.tree_util.tree_map(
         lambda a: P(axis, *([None] * (a.ndim - 1))), params_staged)
-    fn = _smap(shard_fn, mesh, in_specs=(pspec, P()), out_specs=P(axis))
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(pspec, P()),
+                       out_specs=P(axis))
     out = fn(params_staged, xs)[-1]
     return out.reshape(B, *x.shape[1:])

@@ -68,27 +68,33 @@ def test_dbscan_odd_block_size():
     np.testing.assert_array_equal(got, want)
 
 
-def test_parallel_grid_count_path_matches():
-    # GPU grids run programs in parallel: counts must come from the packed
-    # adjacency popcount, not in-kernel j-axis accumulation
-    from unittest import mock
+def test_column_tiled_kernel_matches_xla_twin():
+    # past 1024 columns the kernel walks lane-dense column tiles and pads
+    # to a multiple of 1024; the twin must pad and pack identically
     import repro.kernels.pairdist as P
-    x = _blobs(160, 4, seed=13)
-    with mock.patch.object(P, "_sequential_grid", lambda interpret: False):
-        c1, p1 = P._neighbor_adjacency_pallas(jnp.asarray(x), eps_sq=0.81,
-                                              block=64, interpret=True)
-    c2, p2 = neighbor_adjacency(jnp.asarray(x), 0.9, block=64, impl="xla")
+    x = _blobs(1100, 4, seed=13)
+    c1, p1 = P._neighbor_adjacency_pallas(jnp.asarray(x), eps_sq=0.81,
+                                          block=128, interpret=True)
+    c2, p2 = neighbor_adjacency(jnp.asarray(x), 0.9, block=128, impl="xla")
+    assert p1.shape == (2048, 256)
     np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
     np.testing.assert_array_equal(np.asarray(p1), np.asarray(p2))
 
 
 def test_dispatch_interpret_never_implicit():
-    # CPU resolves to the XLA tiles, accelerators to compiled Pallas;
-    # interpret mode only on explicit request
+    # CPU resolves to the XLA tiles, a TPU to compiled Pallas; interpret
+    # mode only on explicit request
     assert dispatch.resolve("auto") in ("pallas", "xla")
     assert dispatch.resolve("pallas_interpret") == "pallas_interpret"
+    assert dispatch.interpret_mode("pallas_interpret")
     with pytest.raises(ValueError):
         dispatch.resolve("nope")
+    if dispatch.backend() != "tpu":
+        # compiled Pallas off a TPU is an error, never a quiet downgrade
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            dispatch.resolve("pallas")
+        with pytest.raises(RuntimeError, match="only has a Pallas kernel"):
+            dispatch.interpret_mode()
 
 
 # -- streaming DBSCAN vs dense oracle -----------------------------------------

@@ -73,6 +73,8 @@ SSD_CASES = [
     (2, 128, 4, 16, 1, 32, 32, jnp.float32),
     (1, 256, 8, 32, 2, 16, 64, jnp.float32),
     (1, 64, 2, 8, 1, 8, 16, jnp.bfloat16),
+    # two head blocks of 8 per group, two groups: the grid's head axis
+    (1, 64, 32, 8, 2, 8, 16, jnp.float32),
 ]
 
 

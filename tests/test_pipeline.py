@@ -33,7 +33,8 @@ ref = x
 for i in range(L):
     ref = layer(ws[i], ref)
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = jax.make_mesh((4,), ("stage",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 staged = stage_split({"w": ws}, 4)
 out = gpipe_apply(staged["w"], x, stage_fn, mesh=mesh, n_microbatches=4)
 np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

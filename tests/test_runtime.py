@@ -107,3 +107,60 @@ def test_elastic_restore_roundtrip(tmp_path, rng_key):
     assert hasattr(l0, "sharding")
     np.testing.assert_array_equal(
         np.asarray(jax.tree_util.tree_leaves(state)[0]), np.asarray(l0))
+
+
+@pytest.mark.parametrize("error,infeasible", [
+    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm", True),
+    ("INVALID_ARGUMENT: Mosaic failed to compile TPU kernel", False),
+])
+def test_measured_objective_only_oom_is_infeasible(monkeypatch, error,
+                                                   infeasible):
+    import repro.runtime.loop as loop
+
+    tr = Trainer(CFG, SHAPE, OC, DEFAULT_TUNABLES, seed=0)
+
+    def failing_step(*_):
+        def step(state, batch):
+            raise jax.errors.JaxRuntimeError(error)
+        return step
+
+    monkeypatch.setattr(loop, "make_train_step", failing_step)
+    objective = tr.measured_objective()
+    try:
+        if infeasible:
+            assert objective(DEFAULT_TUNABLES) == float("inf")
+            assert tr.infeasible == 1
+        else:
+            with pytest.raises(jax.errors.JaxRuntimeError, match="Mosaic"):
+                objective(DEFAULT_TUNABLES)
+            assert tr.infeasible == 0
+    finally:
+        tr.pipeline.close()
+
+
+def test_peak_flops_table_keyed_by_device_kind():
+    from repro.runtime.telemetry import PEAK_FLOPS, TelemetryEmitter, peak_flops
+
+    assert peak_flops("TPU v5 lite") == 197e12
+    assert peak_flops("cpu") == 2e11
+    with pytest.raises(KeyError, match="no peak FLOP/s"):
+        peak_flops("TPU v0 imaginary")
+    kind = jax.devices()[0].device_kind
+    assert TelemetryEmitter(seq_len=8, global_batch=1).peak == PEAK_FLOPS[kind]
+
+
+def test_compile_cache_dir_env_wins_else_fixed_in_checkout(monkeypatch):
+    from pathlib import Path
+    from repro.runtime import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compile_cache.enable_compile_cache() == "/somewhere/else"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        repo = Path(__file__).resolve().parents[1]
+        assert got == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
