@@ -13,7 +13,6 @@ Implements paper Algorithm 2 + the automated training pipeline (§7):
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -28,6 +27,7 @@ from repro.core.knowledge import WorkloadDB
 from repro.core.lstm import HORIZONS, PredictorConfig, WorkloadPredictor
 from repro.core.synthesizer import sample_pure, synthesize
 from repro.core.windows import WindowSeries, rate_of_change
+from repro.runtime import spans
 
 
 # fast-path training bounds: bootstrap draws per tree, predictor training
@@ -83,13 +83,17 @@ class KermitAnalyser:
     # -- Algorithm 2 ----------------------------------------------------------
 
     def discover(self, ws: WindowSeries) -> AnalysisReport:
-        t0 = time.perf_counter()
+        with spans.timed("kermit.analyse.discover") as t:
+            rep = self._discover(ws)
+        rep.discover_seconds = t.seconds
+        return rep
+
+    def _discover(self, ws: WindowSeries) -> AnalysisReport:
         rep = AnalysisReport(n_windows=len(ws))
         trans = self.detector.batch(ws)
         rep.n_transition_windows = int(trans.sum())
         steady_idx = np.where(~trans)[0]
         if steady_idx.size == 0:
-            rep.discover_seconds = time.perf_counter() - t0
             return rep
         X = ws.mean[steady_idx]
         labels = dbscan(X, self.eps, self.min_pts, impl=self.dbscan_impl)
@@ -127,7 +131,6 @@ class KermitAnalyser:
             if r != u:
                 window_labels[window_labels == u] = r
         self.db.save()
-        rep.discover_seconds = time.perf_counter() - t0
         return rep
 
     # -- training pipeline (§7.2 steps 1-9) ------------------------------------
@@ -136,10 +139,17 @@ class KermitAnalyser:
               synthesize_hybrids: bool = True, zsl_k: int = 2, seed: int = 0,
               predictor_cfg: Optional[PredictorConfig] = None,
               forest_cfg: Optional[ForestConfig] = None):
-        t0 = time.perf_counter()
         wl = rep.window_labels
         if wl is None or (wl >= 0).sum() == 0:
             return self
+        with spans.timed("kermit.analyse.train") as t:
+            self._train(ws, wl, synthesize_hybrids, zsl_k, seed,
+                        predictor_cfg, forest_cfg)
+        rep.train_seconds = t.seconds
+        return self
+
+    def _train(self, ws, wl, synthesize_hybrids, zsl_k, seed, predictor_cfg,
+               forest_cfg) -> None:
         mask = wl >= 0
         X = ws.mean[mask]
         y = wl[mask]
@@ -229,8 +239,6 @@ class KermitAnalyser:
             None if self.transition_classifier is None
             else self.transition_classifier.params,
             None if self.predictor is None else self.predictor.params])
-        rep.train_seconds = time.perf_counter() - t0
-        return self
 
     def run(self, ws: WindowSeries, **kw) -> AnalysisReport:
         rep = self.discover(ws)

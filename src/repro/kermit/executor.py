@@ -51,7 +51,7 @@ Ships two implementations:
 from __future__ import annotations
 
 import math
-import time
+from contextlib import contextmanager
 from typing import (Callable, Optional, Protocol, Sequence,
                     runtime_checkable)
 
@@ -59,6 +59,7 @@ import numpy as np
 
 from repro.configs.base import (DEFAULT_TUNABLES, TUNABLE_CATEGORIES,
                                 Tunables, tunables_to_arrays)
+from repro.runtime import spans
 
 
 @runtime_checkable
@@ -121,11 +122,13 @@ class MeasureCounters:
         self.current = tunables
         self.applied += 1
 
-    def _count_measure(self, t0: float, n: int = 1,
-                       batch: bool = False) -> None:
-        """Fold one measurement (``n`` candidates) ending now into the
-        counters; ``t0`` is its ``time.perf_counter()`` start."""
-        self.measure_seconds += time.perf_counter() - t0
+    @contextmanager
+    def _measuring(self, n: int = 1, batch: bool = False):
+        """Time one measurement of ``n`` candidates as a ``kermit.probe``
+        span and fold it into the counters once it has finished."""
+        with spans.timed("kermit.probe", candidates=n) as t:
+            yield
+        self.measure_seconds += t.seconds
         self.measured += n
         self.measured_batches += batch
 
@@ -153,21 +156,20 @@ class MeasureCounters:
         ``arrays_fn`` (struct-of-arrays encoding) when available, else loop
         ``scalar_fn``; counters updated either way."""
         candidates = list(candidates)
-        t0 = time.perf_counter()
-        if arrays_fn is not None:
-            costs = np.asarray(arrays_fn(tunables_to_arrays(candidates)),
-                               np.float64).reshape(-1).tolist()
-        else:
-            costs = [float(scalar_fn(c)) for c in candidates]
-        self._count_measure(t0, len(candidates), batch=True)
+        with self._measuring(len(candidates), batch=True):
+            if arrays_fn is not None:
+                costs = np.asarray(arrays_fn(tunables_to_arrays(candidates)),
+                                   np.float64).reshape(-1).tolist()
+            else:
+                costs = [float(scalar_fn(c)) for c in candidates]
         return costs
 
     def _measure_batch_arrays_impl(self, arrays: dict,
                                    arrays_fn: Callable) -> np.ndarray:
         """Shared ``measure_batch_arrays`` body (one vectorized dispatch)."""
-        t0 = time.perf_counter()
-        costs = np.asarray(arrays_fn(arrays)).reshape(-1)
-        self._count_measure(t0, len(costs), batch=True)
+        n = max((len(np.atleast_1d(v)) for v in arrays.values()), default=0)
+        with self._measuring(n, batch=True):
+            costs = np.asarray(arrays_fn(arrays)).reshape(-1)
         return costs
 
 
@@ -199,10 +201,8 @@ class CallableExecutor(MeasureCounters):
         self._count_apply(tunables)
 
     def measure(self) -> float:
-        t0 = time.perf_counter()
-        cost = float(self._objective(self.current))
-        self._count_measure(t0)
-        return cost
+        with self._measuring():
+            return float(self._objective(self.current))
 
     def measure_batch(self, candidates: Sequence[Tunables]) -> list:
         return self._measure_batch_impl(candidates, self._objective,
@@ -308,10 +308,8 @@ class SimulatorExecutor(MeasureCounters):
         self._count_apply(tunables)
 
     def measure(self) -> float:
-        t0 = time.perf_counter()
-        cost = float(self._cost(self.current))
-        self._count_measure(t0)
-        return cost
+        with self._measuring():
+            return float(self._cost(self.current))
 
     def measure_batch(self, candidates: Sequence[Tunables]) -> list:
         return self._measure_batch_impl(candidates, self._cost,

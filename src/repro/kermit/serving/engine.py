@@ -33,7 +33,6 @@ Serving-specific knobs (``configs/base.Tunables``):
 """
 from __future__ import annotations
 
-import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
@@ -43,6 +42,7 @@ import numpy as np
 from repro.configs.base import (DEFAULT_TUNABLES, ModelConfig, ShapeSpec,
                                 Tunables, reduced)
 from repro.configs.registry import get_config
+from repro.runtime import spans
 
 # cache arrays grown/cast between prefill and decode (attention families)
 _CACHE_KV_NAMES = ("k", "v", "k0", "v0")
@@ -184,9 +184,6 @@ class ServeEngine:
         for the whole batch or a per-request vector; the batch runs
         ``max(gen)`` steps and each request's completion time is attributed
         at its own length."""
-        import jax
-        import jax.numpy as jnp
-
         tun = tunables if tunables is not None else self.tunables
         gen_vec = np.full(batch, int(gen), np.int64) \
             if np.isscalar(gen) else np.asarray(gen, np.int64)
@@ -194,16 +191,24 @@ class ServeEngine:
             raise ValueError(f"gen vector shape {gen_vec.shape} != ({batch},)")
         steps = int(gen_vec.max())
         capacity = self.capacity_for(prompt_len, steps, tun)
-        pad = capacity - prompt_len
+        with spans.span("kermit.serve", batch=int(batch),
+                        prompt_len=int(prompt_len), capacity=capacity,
+                        steps=steps):
+            return self._serve(tun, batch, prompt_len, gen_vec, steps,
+                               capacity)
 
+    def _serve(self, tun: Tunables, batch: int, prompt_len: int,
+               gen_vec: np.ndarray, steps: int,
+               capacity: int) -> ServeReport:
+        import jax
+        import jax.numpy as jnp
+
+        pad = capacity - prompt_len
         prefill = self.prefill_step(tun)
         decode = self.decode_step(tun)
         b = self._token_batch(prompt_len, batch)
         cache_dt = None if tun.cache_dtype == "auto" \
             else jnp.dtype(tun.cache_dtype)
-
-        t0 = time.perf_counter()
-        logits, cache = prefill(self.params, b)
 
         def grow(path, a):
             name = str(path[-1].key) if hasattr(path[-1], "key") else ""
@@ -214,9 +219,12 @@ class ServeEngine:
                 if cache_dt is not None:
                     a = a.astype(cache_dt)
             return a
-        cache = jax.tree_util.tree_map_with_path(grow, cache)
-        jax.block_until_ready(logits)
-        prefill_s = time.perf_counter() - t0
+
+        with spans.timed("kermit.prefill") as prefill_t:
+            logits, cache = prefill(self.params, b)
+            with spans.span("kermit.cache_grow"):
+                cache = jax.tree_util.tree_map_with_path(grow, cache)
+            jax.block_until_ready(logits)
 
         # greedy over the real vocabulary: the embedding's padding rows
         # (vocab_padded > vocab) are never a token
@@ -224,24 +232,27 @@ class ServeEngine:
         tokens = jnp.argmax(logits[:, -1, :vocab], -1)[:, None].astype(
             jnp.int32)
         out = [tokens]
-        t0 = time.perf_counter()
-        for i in range(steps):
-            step_batch = {"tokens": tokens,
-                          "pos": jnp.asarray(prompt_len + i, jnp.int32)}
-            logits, cache = decode(self.params, cache, step_batch)
-            tokens = jnp.argmax(logits[:, -1, :vocab], -1)[:, None].astype(
-                jnp.int32)
-            out.append(tokens)
-        jax.block_until_ready(tokens)
-        decode_s = time.perf_counter() - t0
+        with spans.timed("kermit.decode", steps=steps) as decode_t:
+            for i in range(steps):
+                with spans.span("kermit.decode_step"):
+                    step_batch = {"tokens": tokens,
+                                  "pos": jnp.asarray(prompt_len + i,
+                                                     jnp.int32)}
+                    logits, cache = decode(self.params, cache, step_batch)
+                    tokens = jnp.argmax(logits[:, -1, :vocab],
+                                        -1)[:, None].astype(jnp.int32)
+                out.append(tokens)
+            with spans.span("kermit.decode_wait"):
+                jax.block_until_ready(tokens)
+        with spans.span("kermit.collect"):
+            generated = np.asarray(jnp.concatenate(out, 1))
 
         self.stats["serve_calls"] += 1
         self.stats["decode_steps"] += steps
         return ServeReport(
             batch=batch, prompt_len=prompt_len, gen=gen_vec,
-            capacity=capacity, prefill_s=prefill_s, decode_s=decode_s,
-            steps=steps,
-            generated=np.asarray(jnp.concatenate(out, 1)))
+            capacity=capacity, prefill_s=prefill_t.seconds,
+            decode_s=decode_t.seconds, steps=steps, generated=generated)
 
     def serve_legacy(self, batch: int, prompt_len: int, gen: int,
                      tun: Tunables) -> dict:

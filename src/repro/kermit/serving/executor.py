@@ -27,7 +27,6 @@ that reconfigures the very system it observes.
 from __future__ import annotations
 
 import dataclasses
-import time
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -101,7 +100,6 @@ class ServeExecutor(MeasureCounters):
         self._cursor = 0
         self.windows_served = 0
         self.window_log: list = []        # per committed window: p99/mean/...
-        self.request_latencies: list = [] # flat committed latency samples (s)
         self._probe: Optional[RequestWindow] = \
             self.windows[0] if self.windows else None
         self._unit: Optional[float] = None    # calibrated service unit (s)
@@ -134,10 +132,8 @@ class ServeExecutor(MeasureCounters):
         self.engine.apply(tunables)
 
     def measure(self) -> float:
-        t0 = time.perf_counter()
-        cost = self._probe_cost(self.current)
-        self._count_measure(t0)
-        return cost
+        with self._measuring():
+            return self._probe_cost(self.current)
 
     def measure_batch(self, candidates: Sequence[Tunables]) -> list:
         return self._measure_batch_impl(candidates, self._probe_cost, None)
@@ -256,7 +252,6 @@ class ServeExecutor(MeasureCounters):
             "tokens_per_s": stats["tokens_per_s"],
             "tunables": self.current.as_dict(),
         })
-        self.request_latencies.extend(float(x) for x in stats["latencies"])
         return self._telemetry(win, stats)
 
     def telemetry_stream(self):
@@ -296,18 +291,17 @@ class ServeExecutor(MeasureCounters):
             "windows_served": self.windows_served,
             "unit": self._unit,
             "window_log": [dict(w) for w in self.window_log],
-            "request_latencies": list(self.request_latencies),
         })
         return state
 
     def restore_state(self, state: dict) -> None:
+        """Restore from ``export_state``; the per-request latency list that
+        older snapshots hold is ignored."""
         MeasureCounters.restore_state(self, state)
         self._cursor = int(state["cursor"])
         self.windows_served = int(state["windows_served"])
         self._unit = state["unit"]
         self.window_log = [dict(w) for w in state["window_log"]]
-        self.request_latencies = [float(x)
-                                 for x in state["request_latencies"]]
         if self._cursor > 0:
             self._probe = self.windows[min(self._cursor,
                                            len(self.windows)) - 1]

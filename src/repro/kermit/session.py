@@ -40,6 +40,7 @@ from repro.core.plugin import KermitPlugin, PluginStats
 from repro.kermit.config import KermitConfig, resolve_impl
 from repro.kermit.events import AutonomicEvent, EventKind
 from repro.kermit.executor import Executor, ExecutorObjective
+from repro.runtime import spans
 from repro.runtime.checkpoint import load_snapshot, save_snapshot
 
 # -- durable-session snapshot schema ----------------------------------------
@@ -258,13 +259,16 @@ class KermitSession:
         W = self.monitor.window_size
         interval = self.config.analysis.interval
         i = 0
-        while i < len(samples):
-            win_left = max(interval - self._since_analysis, 1)
-            need = max(win_left * W - self.monitor.pending_samples, 1)
-            chunk = samples[i:i + need]
-            i += len(chunk)
-            for ctx in self.monitor.ingest_array(chunk):
-                self._on_context(ctx)
+        with spans.span("kermit.step_batch", samples=len(samples)):
+            while i < len(samples):
+                win_left = max(interval - self._since_analysis, 1)
+                need = max(win_left * W - self.monitor.pending_samples, 1)
+                chunk = samples[i:i + need]
+                i += len(chunk)
+                with spans.span("kermit.monitor"):
+                    contexts = self.monitor.ingest_array(chunk)
+                for ctx in contexts:
+                    self._on_context(ctx)
         return self.current
 
     def run(self, samples=None) -> Tunables:
@@ -319,9 +323,11 @@ class KermitSession:
             self._since_analysis = 0
             ws = self.monitor.window_series()
             if ws is not None and len(ws) >= ac.min_windows:
-                rep = self.analyser.run(
-                    ws, synthesize_hybrids=ac.synthesize_hybrids,
-                    zsl_k=ac.zsl_k)
+                with spans.span("kermit.analyse", windows=len(ws)) as sp:
+                    rep = self.analyser.run(
+                        ws, synthesize_hybrids=ac.synthesize_hybrids,
+                        zsl_k=ac.zsl_k)
+                    sp.note(clusters=rep.clusters)
                 self.monitor.classifier = self.analyser.classifier
                 self.monitor.predictor = self.analyser.predictor
                 self._last_analysis_seconds = rep.analysis_seconds
@@ -351,7 +357,9 @@ class KermitSession:
             self._record(AutonomicEvent(
                 ctx.window_id, EventKind.TRANSITION.value, label))
         if label != self._last_label and not ctx.in_transition:
-            tun = self.plugin.on_resource_request(self._objective(), ctx=ctx)
+            with spans.span("kermit.plan"):
+                tun = self.plugin.on_resource_request(self._objective(),
+                                                      ctx=ctx)
             if tun != self.current:
                 self._record(AutonomicEvent(
                     ctx.window_id, EventKind.RETUNE.value, label,

@@ -121,20 +121,16 @@ class RooflineExecutor(MeasureCounters):
         return (rl.compute_s, rl.memory_s, rl.collective_s)
 
     def measure(self) -> float:
-        t0 = time.perf_counter()
-        est = float(max(self._probe_one(self.current)))
-        self._count_measure(t0)
-        return est
+        with self._measuring():
+            return float(max(self._probe_one(self.current)))
 
     def measure_batch(self, candidates) -> list:
         candidates = list(candidates)
-        t0 = time.perf_counter()
-        # vectorized roofline reduction over the whole knob sweep
-        terms = np.array([self._probe_one(c) for c in candidates],
-                         np.float64).reshape(-1, 3)
-        est = terms.max(axis=1)
-        self._count_measure(t0, len(candidates), batch=True)
-        return [float(e) for e in est]
+        with self._measuring(len(candidates), batch=True):
+            # vectorized roofline reduction over the whole knob sweep
+            terms = np.array([self._probe_one(c) for c in candidates],
+                             np.float64).reshape(-1, 3)
+        return [float(e) for e in terms.max(axis=1)]
 
 
 def hillclimb(arch: str, shape_name: str, *, multi_pod=False):

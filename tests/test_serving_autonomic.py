@@ -144,6 +144,20 @@ def test_counter_surface_parity_with_simulator(engine):
         assert key in state, key
 
 
+def test_restore_accepts_a_snapshot_with_request_latencies(engine):
+    """Snapshots written before the flat per-request latency list was
+    dropped still restore; the list is not carried forward."""
+    srv = _chat_executor(engine, n_windows=2)
+    next(srv.telemetry_stream())
+    state = srv.export_state()
+    assert "request_latencies" not in state
+    old = dict(state, request_latencies=[0.5, 0.25, 0.125, 1.0])
+    back = _chat_executor(engine, n_windows=2)
+    back.restore_state(old)
+    assert back.export_state() == state
+    assert not hasattr(back, "request_latencies")
+
+
 def test_probe_cost_is_tail_aware(engine):
     srv = _chat_executor(engine, tail_weight=1.0)
     stats = srv.probe_stats(INITIAL)
