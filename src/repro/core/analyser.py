@@ -10,9 +10,18 @@ Implements paper Algorithm 2 + the automated training pipeline (§7):
      rate-of-change transition windows (TransitionClassifier), synthesized
      hybrids (ZSL), label sequences (WorkloadPredictor)
   5. classifiers are (re)trained
+
+``KermitAnalyser.start`` runs the same analysis on the process's one Analyse
+worker thread (``ANALYSIS_WORKER``) against a private fork of the knowledge
+base; ``PendingAnalysis.join`` installs its models and knowledge.
 """
 from __future__ import annotations
 
+import atexit
+import copy
+import queue
+import threading
+from concurrent.futures import Future, wait
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -53,6 +62,70 @@ class AnalysisReport:
     @property
     def analysis_seconds(self) -> float:
         return self.discover_seconds + self.train_seconds
+
+
+class AnalysisWorker:
+    """The process's one Analyse thread: runs the jobs handed to it one at a
+    time, in the order they came, so every session of a process shares one
+    thread.  It is a daemon thread, started at the first job, so the
+    process can exit with it running; at exit the process waits a bounded
+    time for the jobs already handed in (``drain``), since a thread still
+    inside a compiled call when the interpreter finalizes aborts it."""
+
+    def __init__(self):
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()
+        self._thread: Optional[threading.Thread] = None
+        self._lock = threading.Lock()
+
+    def submit(self, fn, *args, **kw) -> Future:
+        fut: Future = Future()
+        with self._lock:
+            if self._thread is None or not self._thread.is_alive():
+                self._thread = threading.Thread(
+                    target=self._serve, name="kermit-analyse", daemon=True)
+                self._thread.start()
+        self._jobs.put((fut, fn, args, kw))
+        return fut
+
+    def drain(self, timeout: Optional[float] = None) -> bool:
+        """Wait until every job handed in so far has finished; False if
+        ``timeout`` seconds passed first."""
+        if self._thread is None or not self._thread.is_alive():
+            return True
+        return not wait([self.submit(lambda: None)], timeout).not_done
+
+    def _serve(self) -> None:
+        while True:
+            fut, fn, args, kw = self._jobs.get()
+            try:
+                fut.set_result(fn(*args, **kw))
+            except BaseException as e:       # handed to the joining thread
+                fut.set_exception(e)
+
+
+ANALYSIS_WORKER = AnalysisWorker()
+atexit.register(ANALYSIS_WORKER.drain, 120.0)
+
+
+class PendingAnalysis:
+    """An analysis running on ``ANALYSIS_WORKER``; ``join`` waits for it
+    and installs what it produced."""
+
+    def __init__(self, analyser: "KermitAnalyser", job: "KermitAnalyser",
+                 future: Future):
+        self.analyser, self.job, self.future = analyser, job, future
+
+    def join(self) -> "AnalysisReport":
+        """Wait for the analysis, then install its classifiers, predictor
+        and knowledge into the analyser and its WorkloadDB; re-raises what
+        the analysis raised."""
+        rep = self.future.result()
+        a, job = self.analyser, self.job
+        a.classifier = job.classifier
+        a.transition_classifier = job.transition_classifier
+        a.predictor = job.predictor
+        a.db.adopt(job.db)
+        return rep
 
 
 class KermitAnalyser:
@@ -241,6 +314,18 @@ class KermitAnalyser:
             None if self.predictor is None else self.predictor.params])
 
     def run(self, ws: WindowSeries, **kw) -> AnalysisReport:
-        rep = self.discover(ws)
-        self.train(ws, rep, **kw)
+        with spans.span("kermit.analyse", windows=len(ws)) as sp:
+            rep = self.discover(ws)
+            self.train(ws, rep, **kw)
+            sp.note(clusters=rep.clusters)
         return rep
+
+    def start(self, ws: WindowSeries, **kw) -> PendingAnalysis:
+        """``run(ws, **kw)`` on the Analyse worker.  The job runs on a copy
+        of this analyser over a ``fork`` of its WorkloadDB, so nothing this
+        analyser or its store hold changes until ``PendingAnalysis.join``.
+        ``ws`` must not change meanwhile (``window_series(copy=True)``)."""
+        job = copy.copy(self)
+        job.db = self.db.fork()
+        return PendingAnalysis(self, job,
+                               ANALYSIS_WORKER.submit(job.run, ws, **kw))

@@ -45,6 +45,7 @@ The knowledge base persists under the HDFS-like zone layout:
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 import json
 import time
@@ -617,6 +618,24 @@ class WorkloadDB:
                 "detail": {"synthetic": victim.is_synthetic,
                            "had_optimal": victim.has_optimal}})
         self._dirty()
+
+    def fork(self) -> "WorkloadDB":
+        """A private copy for an analysis that runs off the serving thread:
+        what the analysis writes reaches this store only when ``adopt``
+        takes it over."""
+        twin = copy.copy(self)
+        twin.records = copy.deepcopy(self.records)
+        twin.aliases = dict(self.aliases)
+        twin._journal = list(self._journal)
+        twin._arrays = None
+        return twin
+
+    def adopt(self, twin: "WorkloadDB") -> None:
+        """Take over the records, aliases, label counter and journal that
+        an analysis wrote into a ``fork`` of this store."""
+        self.records, self.aliases = twin.records, twin.aliases
+        self._next_label, self._journal = twin._next_label, twin._journal
+        self._arrays = twin._arrays
 
     def drain_events(self) -> list[dict]:
         """Hand the queued drift/merge/evict journal entries to the caller

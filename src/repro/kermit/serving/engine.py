@@ -83,9 +83,9 @@ class ServeReport:
         if self.completion_s is None:
             # decode cost attributed uniformly per step: a request that
             # needs g tokens completes after g steps of the shared batch
-            step_s = self.decode_s / max(self.steps, 1)
-            self.completion_s = self.prefill_s + step_s * np.asarray(
-                self.gen, np.float64)
+            # (as a share of decode_s, so the longest ends at total_s exactly)
+            share = np.asarray(self.gen, np.float64) / max(self.steps, 1)
+            self.completion_s = self.prefill_s + self.decode_s * share
 
     @property
     def total_s(self) -> float:
@@ -203,18 +203,21 @@ class ServeEngine:
         import jax
         import jax.numpy as jnp
 
+        from repro.models import model as M
+
         pad = capacity - prompt_len
         prefill = self.prefill_step(tun)
         decode = self.decode_step(tun)
         b = self._token_batch(prompt_len, batch)
         cache_dt = None if tun.cache_dtype == "auto" \
             else jnp.dtype(tun.cache_dtype)
+        seq_axis = M.cache_seq_axis(self.cfg)
 
         def grow(path, a):
             name = str(path[-1].key) if hasattr(path[-1], "key") else ""
             if name in _CACHE_KV_NAMES and a.ndim >= 4:
                 padding = [(0, 0)] * a.ndim
-                padding[-3] = (0, pad)
+                padding[seq_axis] = (0, pad)
                 a = jnp.pad(a, padding)
                 if cache_dt is not None:
                     a = a.astype(cache_dt)

@@ -12,6 +12,19 @@ tree and closes it through a pluggable ``Executor``:
              so ``session.step(sample)`` needs no threaded objective
   Knowledge  WorkloadDB persists across runs
 
+Analyse runs off the serving thread: at an analysis boundary the session
+hands ``KermitAnalyser.run`` to the process's Analyse worker and returns.
+It joins the analysis (installs its classifier, predictor, knowledge and
+events, then runs that window's plan check) before the next samples are
+ingested, and before anything reads or writes the analyser's or the
+knowledge base's state: a plan when the window's label changed, ``save``,
+``checkpoint``, ``summary``, ``invalidate``, ``close``, the end of
+``run``, and each batch of ``run_live`` (whose next pull serves traffic
+under the applied configuration).  ``sync()`` joins on request.
+Every decision is the one an inline analysis gives, window for window; a
+caller that serves between ``step_batch`` calls applies a plan that an
+analysis forced when it hands in the next window's telemetry.
+
 Telemetry sinks subscribe to the typed event stream instead of polling:
 
     session.subscribe(EventKind.RETUNE, on_retune, replay=16)
@@ -21,6 +34,7 @@ long-running managed loops hold constant memory.
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import asdict
 from pathlib import Path
@@ -168,6 +182,9 @@ class KermitSession:
         self.events_total = 0
         self._last_analysis_seconds: Optional[float] = None
         self._subscribers: list = []     # [(kind | None, fn)], insertion order
+        self._pending = None             # (PendingAnalysis, its window's ctx)
+        self.background_analyses = 0     # analyses handed to the worker
+        self.analysis_wait_s = 0.0       # serving-thread seconds in joins
 
     # -- Execute binding -------------------------------------------------------
 
@@ -245,6 +262,7 @@ class KermitSession:
     def step(self, sample) -> Tunables:
         """Feed one telemetry sample; returns the Tunables the managed system
         should run with (changes only at window boundaries)."""
+        self.sync()
         ctx = self.monitor.ingest(sample)
         if ctx is None:
             return self.current
@@ -261,6 +279,7 @@ class KermitSession:
         i = 0
         with spans.span("kermit.step_batch", samples=len(samples)):
             while i < len(samples):
+                self.sync()
                 win_left = max(interval - self._since_analysis, 1)
                 need = max(win_left * W - self.monitor.pending_samples, 1)
                 chunk = samples[i:i + need]
@@ -280,7 +299,9 @@ class KermitSession:
                 raise ValueError(
                     "run() needs samples: none given and the bound executor "
                     "provides no telemetry stream")
-        return self.step_batch(samples)
+        self.step_batch(samples)
+        self.sync()
+        return self.current
 
     def run_live(self, stream) -> Tunables:
         """Drive the loop over a *live* window stream — an iterable yielding
@@ -292,12 +313,51 @@ class KermitSession:
         the configuration the loop chooses."""
         for samples in stream:
             self.step_batch(np.asarray(samples, np.float32))
+            # pulling the next batch serves it under the applied
+            # configuration, so a plan that an analysis forces lands first
+            self.sync()
         return self.current
 
     def invalidate(self) -> None:
         """Force a plan request at the next steady window — e.g. after an
         external reconfiguration invalidated the active choice."""
+        self.sync()
         self._last_label = None
+
+    def sync(self) -> None:
+        """Join the analysis running on the Analyse worker, if any: wait for
+        it, install its classifier, predictor, knowledge and events, then
+        run its window's plan check."""
+        if self._pending is None:
+            return
+        pending, ctx = self._pending
+        self._pending = None
+        with spans.span("kermit.analyse_join"):
+            t0 = time.perf_counter()
+            rep = pending.join()
+            self.analysis_wait_s += time.perf_counter() - t0
+            self.monitor.classifier = self.analyser.classifier
+            self.monitor.predictor = self.analyser.predictor
+            self._last_analysis_seconds = rep.analysis_seconds
+            self._record(AutonomicEvent(
+                ctx.window_id, EventKind.ANALYSIS.value, ctx.current_label,
+                detail={"clusters": rep.clusters,
+                        "new": rep.new_labels,
+                        "drifted": rep.drifted_labels,
+                        "seconds": rep.analysis_seconds}))
+            # Knowledge-phase adaptation events (drift / merge / evict)
+            # journaled by the WorkloadDB during the run surface on the
+            # typed stream; adaptation touching the active workload forces
+            # a re-plan at the next steady window — the loop re-tunes a
+            # drifted or merged class without any human call
+            for je in self.db.drain_events():
+                self._record(AutonomicEvent(
+                    ctx.window_id, EventKind(je["kind"]).value,
+                    je["label"], detail=je["detail"]))
+                if self._last_label is not None and self._last_label in (
+                        je["label"], je["detail"].get("absorbed")):
+                    self.invalidate()
+        self._plan(ctx)
 
     # -- per-window analyze/plan/execute ---------------------------------------
 
@@ -317,40 +377,25 @@ class KermitSession:
                     self._pending_fault = dict(fe)
                     self.invalidate()
 
-        # off-line subsystem cadence (A of MAPE-K)
+        # off-line subsystem cadence (A of MAPE-K): the analysis runs on the
+        # Analyse worker and this window's plan check waits for its join —
+        # now, when the label changed and the plan reads what it writes
         ac = self.config.analysis
         if self._since_analysis >= ac.interval:
             self._since_analysis = 0
-            ws = self.monitor.window_series()
+            ws = self.monitor.window_series(copy=True)
             if ws is not None and len(ws) >= ac.min_windows:
-                with spans.span("kermit.analyse", windows=len(ws)) as sp:
-                    rep = self.analyser.run(
-                        ws, synthesize_hybrids=ac.synthesize_hybrids,
-                        zsl_k=ac.zsl_k)
-                    sp.note(clusters=rep.clusters)
-                self.monitor.classifier = self.analyser.classifier
-                self.monitor.predictor = self.analyser.predictor
-                self._last_analysis_seconds = rep.analysis_seconds
-                self._record(AutonomicEvent(
-                    ctx.window_id, EventKind.ANALYSIS.value,
-                    ctx.current_label,
-                    detail={"clusters": rep.clusters,
-                            "new": rep.new_labels,
-                            "drifted": rep.drifted_labels,
-                            "seconds": rep.analysis_seconds}))
-                # Knowledge-phase adaptation events (drift / merge / evict)
-                # journaled by the WorkloadDB during the run surface on the
-                # typed stream; adaptation touching the active workload
-                # forces a re-plan at the next steady window — the loop
-                # re-tunes a drifted or merged class without any human call
-                for je in self.db.drain_events():
-                    self._record(AutonomicEvent(
-                        ctx.window_id, EventKind(je["kind"]).value,
-                        je["label"], detail=je["detail"]))
-                    if self._last_label is not None and self._last_label in (
-                            je["label"], je["detail"].get("absorbed")):
-                        self.invalidate()
+                self._pending = (self.analyser.start(
+                    ws, synthesize_hybrids=ac.synthesize_hybrids,
+                    zsl_k=ac.zsl_k), ctx)
+                self.background_analyses += 1
+                if ctx.current_label != self._last_label and \
+                        not ctx.in_transition:
+                    self.sync()
+                return self.current
+        return self._plan(ctx)
 
+    def _plan(self, ctx: WorkloadContext) -> Tunables:
         # plan/execute at workload boundaries (label change or fresh optimum)
         label = ctx.current_label
         if ctx.in_transition:
@@ -397,6 +442,7 @@ class KermitSession:
 
     def save_knowledge(self, path=None) -> None:
         """Persist the WorkloadDB (to ``knowledge.root`` or an explicit path)."""
+        self.sync()
         self.db.save(path)
 
     # -- durable session state (checkpoint / restore) --------------------------
@@ -455,6 +501,7 @@ class KermitSession:
 
         The write is crash-consistent (temp file + fsync + atomic rename):
         a crash mid-write leaves the previous snapshot intact."""
+        self.sync()
         path = Path(path)
         window = self.monitor.windows_emitted
         label = self._last_label if self._last_label is not None else -1
@@ -585,8 +632,12 @@ class KermitSession:
     # -- lifecycle -------------------------------------------------------------
 
     def close(self) -> None:
-        """Flush + release the monitor's JSONL context stream."""
-        self.monitor.close()
+        """Join a background analysis, then flush + release the monitor's
+        JSONL context stream."""
+        try:
+            self.sync()
+        finally:
+            self.monitor.close()
 
     def __enter__(self) -> "KermitSession":
         return self
@@ -597,6 +648,7 @@ class KermitSession:
     # -- reporting -------------------------------------------------------------
 
     def summary(self) -> dict:
+        self.sync()
         s = self.plugin.stats
         return {
             "impl": self.config.impl,
@@ -613,4 +665,6 @@ class KermitSession:
             "events_retained": len(self.events),
             "pending_fault": self._pending_fault.get("kind")
             if self._pending_fault else None,
+            "background_analyses": self.background_analyses,
+            "analysis_wait_s": self.analysis_wait_s,
         }

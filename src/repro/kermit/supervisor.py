@@ -120,6 +120,7 @@ class KermitSupervisor:
             chunk = samples[pos:pos + take]
             try:
                 session.step_batch(chunk)
+                session.sync()       # the stride's last plan check included
             except self.restart_on as e:
                 self.crashes += 1
                 if self.restores >= self.max_restores:

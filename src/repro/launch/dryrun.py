@@ -69,7 +69,8 @@ def _lower(cfg, shape, tun, oc):
         cache_sds = M.cache_specs(cfg, shape)
         batch_sds = M.input_specs(cfg, shape)
         params_sh = _shardings(rules.param_axes_tree(params_sds, tun.zero3))
-        cache_sh = _shardings(rules.cache_axes_tree(cache_sds))
+        cache_sh = _shardings(rules.cache_axes_tree(
+            cache_sds, M.cache_seq_axis(cfg)))
         batch_sh = _shardings(rules.batch_axes_tree(batch_sds))
         fn = make_serve_step(cfg, tun)
         jitted = jax.jit(fn, in_shardings=(params_sh, cache_sh, batch_sh),

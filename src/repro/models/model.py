@@ -6,6 +6,7 @@ Functions:
   prefill(params, cfg, batch, tun)     -> (logits, cache)
   decode(params, cfg, batch, cache, tun) -> (logits, new_cache)
   init_cache(cfg, batch, seq)          -> cache pytree (zeros; eval_shape-able)
+  cache_seq_axis(cfg)                  -> sequence axis of the k/v cache leaves
   input_specs(cfg, shape)              -> {name: ShapeDtypeStruct} for the batch
 """
 from __future__ import annotations
@@ -75,6 +76,14 @@ def decode(params, cfg, batch, cache, tun):
     if cfg.family == "hybrid":
         return S.decode_zamba(params, cfg, batch, cache, tun)
     return T.decode_step(params, cfg, batch, cache, tun)
+
+
+def cache_seq_axis(cfg) -> int:
+    """The sequence axis of the ``k``/``v``/``k0``/``v0`` cache leaves,
+    counted from the end: the transformer families keep their cache
+    sequence-major, (..., S, K, B, hd), the layout their decode step reads;
+    the others (..., B, S, K, hd)."""
+    return -3 if cfg.family in ("encdec", "ssm", "hybrid") else -4
 
 
 def init_cache(cfg, batch: int, seq: int):

@@ -4,6 +4,11 @@ Layers are stacked (leading L axis) and applied with lax.scan; the scanned body
 is wrapped in jax.checkpoint with the policy chosen by the ``remat`` tunable.
 Per-layer heterogeneity (gemma2 alternating local/global windows) rides through
 the scan as a per-layer scalar.
+
+The decode KV cache is sequence-major, (L, S, K, B, hd): the layout the TPU
+compiler chooses for the decode step's layer loop, so the donated cache enters
+and leaves the loop without a relayout.  The loop carries the whole stack and
+each layer writes only its new token's keys and values into it.
 """
 from __future__ import annotations
 
@@ -80,18 +85,37 @@ def layer_windows(cfg, n: int):
     return jnp.full((n,), cfg.window, jnp.int32)
 
 
+def _cache_write(c, new, layer, pos):
+    """Write one token's keys or values, (B,1,K,hd), into the cache ``c`` at
+    position ``pos``.  ``c`` is sequence-major: (L,S,K,B,hd) with ``layer``
+    the layer to write, or (S,K,B,hd) with ``layer`` None.  Returns the
+    cache and the layer's part as (B,S,K,hd), which attention reads."""
+    new = new.astype(c.dtype).transpose(1, 2, 0, 3)        # (1,K,B,hd)
+    if layer is None:
+        c = lax.dynamic_update_slice(c, new, (pos, 0, 0, 0))
+        part = c
+    else:
+        c = lax.dynamic_update_slice(c, new[None], (layer, pos, 0, 0, 0))
+        part = lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+    return c, part.transpose(2, 0, 1, 3)
+
+
 def block_apply(p, x, cfg, tun, *, positions, window, prefix_len=0,
-                kv=None, kv_pos=None, kv_len=None, write_pos=None):
-    """One transformer block. If ``kv``/``write_pos`` given -> decode w/ cache."""
+                kv=None, kv_pos=None, kv_len=None, write_pos=None, layer=None):
+    """One transformer block. If ``kv``/``write_pos`` given -> decode w/ cache:
+    ``kv`` is the (k, v) cache, stacked over layers with ``layer`` this
+    block's index, or one layer's own with ``layer`` None (``_cache_write``);
+    the new token's keys and values are written into it at ``write_pos`` and
+    the updated cache is returned."""
     moe_layer = "moe" in p
     h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
     if write_pos is not None:
-        # decode: project single token, update cache, attend over cache
+        # decode: project single token, write it into the cache, attend
+        # over this layer's part of the cache
         q, k1, v1 = L.attn_qkv(p["attn"], h, cfg, positions)
-        ck, cv = kv
-        ck = lax.dynamic_update_slice(ck, k1.astype(ck.dtype), (0, write_pos, 0, 0))
-        cv = lax.dynamic_update_slice(cv, v1.astype(cv.dtype), (0, write_pos, 0, 0))
-        out = L.attention_xla(q, ck, cv, q_pos=positions, kv_pos=kv_pos,
+        ck, lk = _cache_write(kv[0], k1, layer, write_pos)
+        cv, lv = _cache_write(kv[1], v1, layer, write_pos)
+        out = L.attention_xla(q, lk, lv, q_pos=positions, kv_pos=kv_pos,
                               causal=True, window=window, prefix_len=prefix_len,
                               softcap=cfg.attn_softcap, kv_len=kv_len,
                               q_chunk=tun.attn_q_chunk)
@@ -170,26 +194,28 @@ def forward(params, cfg, batch, tun, *, return_cache=False):
     logits = maybe_constrain(logits, ("batch", None, "model"))
     cache = None
     if return_cache:
-        cache = {"k": caches[0], "v": caches[1]}
+        # (L,B,S,K,hd) -> the sequence-major layout decode reads
+        cache = {"k": caches[0].transpose(0, 2, 3, 1, 4),
+                 "v": caches[1].transpose(0, 2, 3, 1, 4)}
         if kv0 is not None:
-            cache["k0"], cache["v0"] = kv0
+            cache["k0"], cache["v0"] = (a.transpose(1, 2, 0, 3) for a in kv0)
     return logits, aux_total, cache
 
 
 def decode_step(params, cfg, batch, cache, tun):
     """One-token decode. batch: {"tokens": (B,1), "pos": scalar}.
-    cache: {"k": (L,B,S,K,hd), "v": ...}. Returns (logits, new_cache)."""
+    cache: {"k": (L,S,K,B,hd), "v": ...}, donated by the caller so the
+    token's writes land in place. Returns (logits, new_cache)."""
     pos = batch["pos"]
     tok = params["embed"][batch["tokens"]]
     if cfg.scale_embed:
         tok = tok * jnp.asarray(cfg.d_model ** 0.5, tok.dtype)
     x = tok
     positions = pos[None] if pos.ndim == 0 else pos
-    S = cache["k"].shape[2]
+    S = cache["k"].shape[1]
     kv_pos = jnp.arange(S)
     kv_len = pos + 1
     n_scan = cfg.n_layers
-    offset = 0
     new0 = None
     if "layer0" in params:
         x, new0, _ = block_apply(
@@ -199,16 +225,21 @@ def decode_step(params, cfg, batch, cache, tun):
         n_scan -= 1
     wins = layer_windows(cfg, n_scan)
 
-    def body(x, xs):
-        p_l, win, ck, cv = xs
-        x, (nk, nv), _ = block_apply(p_l, x, cfg, tun, positions=positions,
+    # the stacked cache rides in the carry, so each layer writes only its
+    # new token into the donated buffer: no per-step copy of the cache
+    def body(carry, xs):
+        x, ck, cv = carry
+        p_l, win, layer = xs
+        x, (ck, cv), _ = block_apply(p_l, x, cfg, tun, positions=positions,
                                      window=win, kv=(ck, cv), kv_pos=kv_pos,
-                                     kv_len=kv_len, write_pos=pos)
-        return x, (nk, nv)
+                                     kv_len=kv_len, write_pos=pos,
+                                     layer=layer)
+        return (x, ck, cv), None
 
-    x, (nk, nv) = lax.scan(body, x, (params["layers"], wins,
-                                     cache["k"], cache["v"]),
-                           unroll=n_scan if tun.layer_unroll else 1)
+    (x, nk, nv), _ = lax.scan(body, (x, cache["k"], cache["v"]),
+                              (params["layers"], wins,
+                               jnp.arange(n_scan, dtype=jnp.int32)),
+                              unroll=n_scan if tun.layer_unroll else 1)
     x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
     head = params.get("head")
     logits = (jnp.einsum("bsd,dv->bsv", x, head) if head is not None
@@ -227,8 +258,8 @@ def init_cache(cfg, batch: int, seq: int):
     cache = {}
     if cfg.moe is not None and cfg.moe.first_layer_dense:
         n_scan -= 1
-        cache["k0"] = jnp.zeros((batch, seq, K, hd), dtype)
-        cache["v0"] = jnp.zeros((batch, seq, K, hd), dtype)
-    cache["k"] = jnp.zeros((n_scan, batch, seq, K, hd), dtype)
-    cache["v"] = jnp.zeros((n_scan, batch, seq, K, hd), dtype)
+        cache["k0"] = jnp.zeros((seq, K, batch, hd), dtype)
+        cache["v0"] = jnp.zeros((seq, K, batch, hd), dtype)
+    cache["k"] = jnp.zeros((n_scan, seq, K, batch, hd), dtype)
+    cache["v"] = jnp.zeros((n_scan, seq, K, batch, hd), dtype)
     return cache

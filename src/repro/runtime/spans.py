@@ -12,12 +12,17 @@ end from ``time.perf_counter_ns()`` and its attributes, and enters
 ``jax.profiler.TraceAnnotation`` under the same name and attributes: under
 a profiler session it lies on the host plane, on the clock of the device's
 programs.  Backend compiles (``jax.monitoring``) and Python GC pauses
-(``gc.callbacks``) are charged to the innermost open span, or to
-``SpanLog.outside`` when none is open.  Spans nest on one thread.
+(``gc.callbacks``) are charged to the innermost open span of the thread
+they ran on, or to ``SpanLog.outside`` when none is open.  Spans nest per
+thread: a span's parent is the innermost span open on its own thread, so
+the Analyse worker's spans and the serving thread's interleave in one log
+without nesting into each other.
 """
 from __future__ import annotations
 
 import gc
+import itertools
+import threading
 import time
 
 COLUMNS = ("id", "parent", "name", "start_ns", "end_ns", "child_ns",
@@ -37,12 +42,21 @@ class SpanLog:
         self.records: list = []       # one tuple per span, in COLUMNS order
         self.dropped = 0
         self.outside = {"compiles": 0, "compile_ns": 0, "gc_ns": 0}
-        self._stack: list = []
-        self._next_id = 0
+        self._local = threading.local()     # .stack: this thread's open spans
+        self._ids = itertools.count()
+        self._lock = threading.Lock()       # a closing span's record or drop
         self._gc_t0 = None
 
+    @property
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
     def _charge(self, key: str, ns: int) -> None:
-        into = self._stack[-1].counts if self._stack else self.outside
+        stack = self._stack
+        into = stack[-1].counts if stack else self.outside
         into[key] += ns
         if key == "compile_ns":
             into["compiles"] += 1
@@ -118,31 +132,33 @@ class _Span(_Timer):
 
     def __enter__(self):
         log = self.log
-        self.parent = log._stack[-1].id if log._stack else None
-        self.id = log._next_id
-        log._next_id += 1
+        stack = log._stack
+        self.parent = stack[-1].id if stack else None
+        self.id = next(log._ids)
         self.counts = {"child_ns": 0, "compiles": 0, "compile_ns": 0,
                        "gc_ns": 0}
         self._ann = _annotation(self.name, **self.attrs)
         self._ann.__enter__()
-        log._stack.append(self)
+        stack.append(self)
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         self.t1 = time.perf_counter_ns()
         log, c, dt = self.log, self.counts, self.t1 - self.t0
-        log._stack.pop()
+        stack = log._stack
+        stack.pop()
         self._ann.__exit__(*exc)
-        if log._stack:
-            log._stack[-1].counts["child_ns"] += dt
-        if len(log.records) < log.capacity:
-            log.records.append((
-                self.id, self.parent, self.name, self.t0, self.t1,
-                c["child_ns"], c["compiles"], c["compile_ns"], c["gc_ns"],
-                self.attrs or None))
-        else:
-            log.dropped += 1
+        if stack:
+            stack[-1].counts["child_ns"] += dt
+        with log._lock:
+            if len(log.records) < log.capacity:
+                log.records.append((
+                    self.id, self.parent, self.name, self.t0, self.t1,
+                    c["child_ns"], c["compiles"], c["compile_ns"],
+                    c["gc_ns"], self.attrs or None))
+            else:
+                log.dropped += 1
 
 
 def span(name: str, **attrs):

@@ -169,7 +169,7 @@ def _tp_size() -> int:
     return int(_MESH.shape.get("model", 1)) if _MESH is not None else 1
 
 
-def _cache_axes(name: str, shape):
+def _cache_axes(name: str, shape, seq_axis: int = -3):
     r = len(shape)
     if name in ("k", "v", "k0", "v0", "xk", "xv"):
         # (B, S, K, hd). When kv-heads divide tp, shard heads over 'model'
@@ -179,14 +179,20 @@ def _cache_axes(name: str, shape):
         # decoded token), while sequence sharding always divides, keeps the
         # per-step append local, and reduces attention with one tiny psum of
         # (B,H,hd) partials + softmax stats. §Perf iterations 0a/0b.
+        # A sequence-major self-attention cache (seq_axis -4) is
+        # (S, K, B, hd) and takes the same axes, permuted.
+        seq_major = seq_axis == -4 and name in ("k", "v", "k0", "v0")
+        b_dim, k_dim = (r - 2, r - 3) if seq_major else (r - 4, r - 2)
         tp = _tp_size()
-        heads_ok = shape[r - 2] % tp == 0
-        if shape[r - 4] == 1:
+        heads_ok = shape[k_dim] % tp == 0
+        if shape[b_dim] == 1:
             base = ((None, "data", "model", None) if heads_ok else
                     (None, ("data", "model"), None, None))
         else:
             base = (("batch", None, "model", None) if heads_ok else
                     ("batch", "model", None, None))
+        if seq_major:
+            base = (base[1], base[2], base[0], base[3])
     elif name == "ssm":
         b = "batch" if shape[r - 4] > 1 else None
         base = (b, "model", None, None)           # (B, H, N, P)
@@ -201,9 +207,11 @@ def _cache_axes(name: str, shape):
     return (None,) * (r - len(base)) + base
 
 
-def cache_axes_tree(cache):
+def cache_axes_tree(cache, seq_axis: int = -3):
+    """Axes of every cache leaf; ``seq_axis`` is the model's
+    ``cache_seq_axis``."""
     def rule(path, leaf):
-        return _cache_axes(_path_names(path)[-1], leaf.shape)
+        return _cache_axes(_path_names(path)[-1], leaf.shape, seq_axis)
     return jax.tree_util.tree_map_with_path(rule, cache)
 
 

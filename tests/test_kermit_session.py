@@ -1,12 +1,14 @@
 """KermitSession facade: config-tree round-trip, event subscription, the
 Execute phase, legacy-shim parity, window-count staleness, knowledge
-persistence (ISSUE 3 acceptance criteria)."""
+persistence, and the Analyse phase off the serving thread."""
 import json
+import threading
 
 import numpy as np
 import pytest
 
 from repro.configs.base import DEFAULT_TUNABLES, Tunables
+from repro.core.analyser import KermitAnalyser
 from repro.core.explorer import Explorer
 from repro.core.monitor import KermitMonitor, WorkloadContext
 from repro.core.plugin import KermitPlugin
@@ -15,6 +17,8 @@ from repro.kermit import (AnalysisConfig, AutonomicEvent, CallableExecutor,
                           EventKind, ExecConfig, KermitConfig, KermitSession,
                           KnowledgeConfig, MonitorConfig, PlanConfig,
                           SimulatorExecutor, resolve_impl)
+from repro.runtime import spans
+from repro.scenarios.runner import _build_config, _build_stack, load_manifest
 
 SPACE = {"microbatches": [1, 2, 4], "remat": ["dots", "none"]}
 
@@ -249,3 +253,185 @@ def test_session_save_knowledge_explicit_path(tmp_path):
     db = WorkloadDB()
     assert db.load(path)
     assert len(db.records) == len(sess.db.records)
+
+
+# -- Analyse off the serving thread --------------------------------------------
+
+SESSION_SCENARIOS = sorted(
+    name for name, spec in load_manifest()["scenarios"].items()
+    if spec.get("kind", "session") == "session")
+
+
+class _InlineSession(KermitSession):
+    """Joins each analysis before the window's step returns: the loop as
+    it ran with Analyse on the serving thread."""
+
+    def _on_context(self, ctx):
+        super()._on_context(ctx)
+        self.sync()
+        return self.current
+
+
+def _window_by_window(session, samples):
+    W = session.monitor.window_size
+    for i in range(0, len(samples), W):
+        session.step_batch(samples[i:i + W])
+    session.sync()
+    return [(e.window_id, e.kind, e.label, e.tunables,
+             {k: v for k, v in e.detail.items() if k != "seconds"})
+            for e in session.events]
+
+
+@pytest.mark.parametrize("scenario", SESSION_SCENARIOS)
+def test_background_analyse_decides_as_inline(scenario):
+    """Fed one window per ``step_batch`` call, as a server feeds it, the
+    loop with Analyse on the worker emits the same events (ANALYSIS and
+    RETUNE among them), labels and committed tunables, window for window,
+    as the same code joining every analysis at once."""
+    spec = load_manifest()["scenarios"][scenario]
+    runs = []
+    for cls in (KermitSession, _InlineSession):
+        ex, chaos = _build_stack(spec, seed=0)
+        with cls(_build_config(spec, "auto"), executor=ex) as session:
+            events = _window_by_window(session, chaos.samples)
+            runs.append((events, session.current, session.summary()))
+    (bg, bg_final, bg_sum), (inline, inline_final, _) = runs
+    kinds = [e[1] for e in bg]
+    assert EventKind.ANALYSIS.value in kinds
+    assert EventKind.RETUNE.value in kinds
+    assert bg == inline
+    assert bg_final == inline_final
+    assert bg_sum["background_analyses"] == kinds.count(
+        EventKind.ANALYSIS.value)
+
+
+@pytest.fixture
+def held_analysis(monkeypatch):
+    """``KermitAnalyser.run`` held, once it has run, until the returned
+    ``release`` is set (or 30 s pass, so a wrong join fails the test rather
+    than hanging it); ``ran`` is set when the hold begins."""
+    ran, release = threading.Event(), threading.Event()
+    run = KermitAnalyser.run
+
+    def held(self, ws, **kw):
+        rep = run(self, ws, **kw)
+        ran.set()
+        release.wait(30)
+        return rep
+
+    monkeypatch.setattr(KermitAnalyser, "run", held)
+    yield ran, release
+    release.set()
+
+
+def _held_session(windows: int):
+    """A session fed ``windows`` windows, one per call, whose last window
+    handed its analysis to the worker; returns (session, samples)."""
+    ex = SimulatorExecutor([("dense_train", 12)], window_size=8, seed=0)
+    sess = KermitSession(
+        _cfg(analysis=AnalysisConfig(interval=windows, min_windows=4)),
+        executor=ex)
+    for w in range(windows):
+        sess.step_batch(ex.samples[8 * w:8 * (w + 1)])
+    return sess, ex.samples
+
+
+def _analyses(sess):
+    return [e for e in sess.events if e.kind == EventKind.ANALYSIS.value]
+
+
+def test_step_batch_returns_before_a_slow_analysis(held_analysis):
+    ran, release = held_analysis
+    sess, samples = _held_session(4)
+    pending, ctx = sess._pending
+    assert ran.wait(30) and not pending.future.done()
+    # the analysis wrote its knowledge into a fork: the session's store,
+    # models and events are untouched until the join
+    assert len(pending.job.db.records) >= 1
+    assert len(sess.db.records) == 0 and sess.analyser.classifier is None
+    assert not _analyses(sess)
+    threading.Timer(0.2, release.set).start()
+    sess.step_batch(samples[32:40])           # joins before ingesting
+    assert sess._pending is None and pending.future.done()
+    analysis, = _analyses(sess)
+    assert analysis.window_id == ctx.window_id
+    assert len(sess.db.records) >= 1
+    assert sess.monitor.classifier is sess.analyser.classifier is not None
+    s = sess.summary()
+    assert s["background_analyses"] == 1
+    assert s["analysis_wait_s"] > 0.05
+
+
+@pytest.mark.parametrize("reader", ["summary", "save_knowledge",
+                                    "checkpoint", "invalidate"])
+def test_readers_of_the_loop_state_join_first(held_analysis, reader,
+                                              tmp_path):
+    sess, _ = _held_session(4)
+    assert sess._pending is not None
+    held_analysis[1].set()
+    path = tmp_path / "state"
+    if reader == "summary":
+        assert sess.summary()["known_workloads"] >= 1
+    elif reader == "save_knowledge":
+        sess.save_knowledge(path)
+        assert json.loads(path.read_text())["records"]
+    elif reader == "checkpoint":
+        sess.checkpoint(path)
+        restored = KermitSession.restore(path)
+        assert len(restored.db.records) == len(sess.db.records) >= 1
+    else:
+        sess.invalidate()
+    assert sess._pending is None and len(_analyses(sess)) == 1
+
+
+def test_a_window_that_plans_joins_its_analysis_first():
+    """When the analysis window's label differs from the planned one, the
+    plan reads the knowledge the analysis writes, so that window's call
+    joins the analysis and plans before it returns."""
+    ex = SimulatorExecutor([("dense_train", 12)], window_size=8, seed=0)
+    sess = KermitSession(_cfg(analysis=AnalysisConfig(interval=4,
+                                                      min_windows=4)),
+                         executor=ex)
+    for w in range(3):
+        sess.step_batch(ex.samples[8 * w:8 * (w + 1)])
+    sess.invalidate()                 # the 4th window's label is now new
+    plan = sess.plugin.on_resource_request
+    seen = []
+
+    def checked(objective, ctx=None):
+        seen.append((ctx.window_id, sess._pending is None,
+                     len(_analyses(sess))))
+        return plan(objective, ctx=ctx)
+
+    sess.plugin.on_resource_request = checked
+    sess.step_batch(ex.samples[24:32])
+    assert sess._pending is None
+    analysis, = _analyses(sess)
+    assert seen == [(analysis.window_id, True, 1)]
+
+
+def test_the_join_is_a_span_and_two_counters():
+    ex = SimulatorExecutor([("dense_train", 8), ("moe_train", 8)],
+                           window_size=8, seed=0)
+    sess = KermitSession(_cfg(analysis=AnalysisConfig(interval=4,
+                                                      min_windows=4)),
+                         executor=ex)
+    log = spans.enable()
+    try:
+        _window_by_window(sess, ex.samples)
+    finally:
+        spans.disable()
+    s = sess.summary()
+    names = [r[2] for r in log.records]
+    assert s["background_analyses"] == len(_analyses(sess)) >= 2
+    assert names.count("kermit.analyse_join") == s["background_analyses"]
+    assert names.count("kermit.analyse") == s["background_analyses"]
+    joins = [r for r in log.records if r[2] == "kermit.analyse_join"]
+    assert s["analysis_wait_s"] <= sum(r[4] - r[3] for r in joins) / 1e9
+    # the worker's spans are its own thread's: never inside the loop's
+    by_id = {r[0]: r for r in log.records}
+    for r in log.records:
+        if r[2] == "kermit.analyse":
+            assert r[1] is None
+        if r[2].startswith("kermit.analyse."):
+            assert by_id[r[1]][2] == "kermit.analyse"

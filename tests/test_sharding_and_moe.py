@@ -61,9 +61,11 @@ def test_batch_and_cache_axes():
     axes = rules.batch_axes_tree(specs)
     assert axes["tokens"] == ("batch", None)
     cache = M.cache_specs(cfg, SHAPES["decode_32k"])
-    caxes = rules.cache_axes_tree(cache)
-    # without a live mesh tp=1 -> kv-heads divide -> head sharding
-    assert caxes["k"][1] == "batch" and caxes["k"][3] == "model"
+    seq_axis = M.cache_seq_axis(cfg)
+    caxes = rules.cache_axes_tree(cache, seq_axis)
+    # the stacked cache is sequence-major, (L, S, K, B, hd); without a live
+    # mesh tp=1 -> kv-heads divide -> head sharding
+    assert caxes["k"][3] == "batch" and caxes["k"][2] == "model"
     # with a 16-way 'model' axis, qwen3 kv=8 doesn't divide -> seq sharding
     from repro.launch.mesh import make_host_mesh
     import jax
@@ -72,8 +74,8 @@ def test_batch_and_cache_axes():
     fake = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
     rules.set_mesh(fake)
     try:
-        caxes1 = rules.cache_axes_tree(cache)
-        assert caxes1["k"][3] == "model"   # tp=1 divides
+        caxes1 = rules.cache_axes_tree(cache, seq_axis)
+        assert caxes1["k"][2] == "model"   # tp=1 divides
     finally:
         rules.set_mesh(None)
     # unit batch (long_500k-style): no batch sharding, seq over both axes

@@ -4,6 +4,8 @@ the innermost open span, and the engine's and the loop's reported times
 taken from the same clock reads as their spans."""
 import gc
 import glob
+import sys
+import threading
 
 import numpy as np
 import pytest
@@ -74,6 +76,62 @@ def test_nesting_parents_attributes_and_self_time(log):
     assert "outer" not in log.totals(lo=o["start_ns"] / 1e9 + 1e-9)
     json_ = log.to_json()
     assert json_["columns"] == list(spans.COLUMNS) and len(json_["spans"]) == 4
+
+
+def test_spans_on_two_threads_nest_per_thread(log):
+    """Two threads open and close spans in lockstep, interleaved: each span's
+    parent is the innermost span of its own thread, and each outer span's
+    self time leaves out only its own thread's children."""
+    step = threading.Barrier(2)
+
+    def work(tag):
+        with spans.span(f"{tag}.outer"):
+            step.wait()
+            with spans.span(f"{tag}.inner"):
+                step.wait()
+            step.wait()
+
+    other = threading.Thread(target=work, args=("b",))
+    other.start()
+    work("a")
+    other.join(30)
+    assert not other.is_alive()
+    rec = {r[2]: dict(zip(spans.COLUMNS, r)) for r in log.records}
+    assert len(rec) == 4 and len({r["id"] for r in rec.values()}) == 4
+    for tag in ("a", "b"):
+        outer, inner = rec[f"{tag}.outer"], rec[f"{tag}.inner"]
+        assert outer["parent"] is None
+        assert inner["parent"] == outer["id"]
+        assert outer["child_ns"] == inner["end_ns"] - inner["start_ns"]
+
+
+def test_spans_from_many_threads_lose_no_update():
+    """More threads than cores, switching as often as the interpreter
+    allows, into a log too small for them all: every span is recorded or
+    counted as dropped, once, under an id of its own."""
+    threads, per = 16, 200
+    log = spans.enable(capacity=threads * per // 2)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work():
+            for _ in range(per):
+                with spans.span("outer"):
+                    with spans.span("inner"):
+                        pass
+
+        pool = [threading.Thread(target=work) for _ in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(60)
+        assert not any(t.is_alive() for t in pool)
+    finally:
+        sys.setswitchinterval(interval)
+        spans.disable()
+    assert len(log.records) == log.capacity
+    assert len(log.records) + log.dropped == 2 * threads * per
+    assert len({r[0] for r in log.records}) == len(log.records)
 
 
 def test_a_full_log_counts_what_it_drops():

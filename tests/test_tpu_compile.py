@@ -107,3 +107,28 @@ def test_qwen2_decode_step_compiles_at_full_width(one_chip):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < V5E_HBM_BYTES
+
+
+def test_qwen2_decode_step_writes_the_cache_in_place(one_chip):
+    """At the benchmark cell's largest capacity the donated cache is the
+    program's output buffer, and the program holds no scratch the size of
+    one layer's keys and values: a relayout or a copy of the cache through
+    the layer loop would need at least that."""
+    cfg = get_config("qwen2-1.5b")
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(lambda: M.init(jax.random.PRNGKey(0),
+                                                   cfg)))
+    cache = on_chip(jax.eval_shape(lambda: M.init_cache(cfg, 8, 2560)))
+    batch = on_chip(M.input_specs(cfg, ShapeSpec("decode", 2560, 8,
+                                                 "decode")))
+    step = jax.jit(make_serve_step(cfg, DEFAULT_TUNABLES),
+                   donate_argnums=(1,))
+    mem = step.lower(params, cache, batch).compile().memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache))
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // cfg.n_layers
