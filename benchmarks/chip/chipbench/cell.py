@@ -203,10 +203,14 @@ class Cell:
                       initial=self.tunables())
         ex._unit = 1.0      # arrivals are in seconds: one unit is a second
         # every shape a probe can reach was compiled and run in set-up, so
-        # a probe replays once instead of running each new shape untimed first
+        # a probe replays once instead of running each new shape untimed
+        # first: every capacity that a warmed (batch, prompt) reaches over
+        # the traffic's outputs runs a warmed program (a state-space program
+        # is the same at every capacity)
         ex._warm.update((tun, tun.serve_batch, P,
-                         self.engine.capacity_for(P, g, tun))
-                        for tun, P, g in self.shapes())
+                         self.engine.capacity_for(P, s, tun))
+                        for tun, P, _ in self.shapes()
+                        for s in range(lo - 1, hi))
         initial = self.tunables()
         cfg = KermitConfig(
             monitor=MonitorConfig(window_size=k["window_size"]),

@@ -12,7 +12,7 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
-from chipbench import registry, report, testing  # noqa: E402
+from chipbench import check, registry, report, testing  # noqa: E402
 
 BENCH = registry.benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -65,6 +65,11 @@ def test_every_part_is_a_file_found_by_name():
         assert entry["source"] == cfg["source"]
         assert entry["reduced"] == cfg["reduced"]
         assert callable(registry.reference(cfg["reference"]).logits_at)
+        # a listed cell can be judged: every number compared has a limit
+        for n in check.NUMBERS:
+            limit = cfg["check"].get(n)
+            assert isinstance(limit, (int, float)) and not isinstance(
+                limit, bool) and limit >= 0, (w["name"], n, limit)
     for m in BENCH["per_layer"]:
         assert callable(registry.metric(m["name"]).read)
 

@@ -99,14 +99,39 @@ def cache_unchanged(engine):
     engine.decode_step = frozen
 
 
+@pytest.mark.parametrize("workload", testing.TINY_CELLS)
 @pytest.mark.parametrize("fault", [alter_token, cache_unchanged])
-def test_broken_timed_path_is_not_correct(tiny, fault):
+def test_broken_timed_path_is_not_correct(tiny, fault, workload):
+    # the state-space cell's cache is its recurrent state
     root, bench = tiny
-    line, _, _ = testing.run_tiny(root, bench, testing.TINY_CELLS[0],
-                                  corrupt=fault)
+    line, _, _ = testing.run_tiny(root, bench, workload, corrupt=fault)
     assert line["correct"] is False
     c = line["checks"]["logit_gap"]
     assert c["value"] > c["limit"]
+
+
+@pytest.mark.parametrize("workload", testing.TINY_CELLS)
+def test_every_probe_shape_is_warm(tiny, workload):
+    """A Plan probe of any batch, prompt and output length the cell's
+    traffic holds finds its shape warmed in set-up, so it replays once."""
+    from chipbench import traffic as T
+    from chipbench.cell import Cell
+    root, _ = tiny
+    cell = Cell(workload, root)
+    cell.build_engine(5)
+    ex, session = cell.session()
+    try:
+        lo, hi = T.output_range(cell.mix)
+        space = cell.cell["plan_space"]
+        for B in space["serve_batch"]:
+            for cache_len in space.get("cache_len", [0]):
+                tun = cell.tunables(serve_batch=B, cache_len=cache_len)
+                for P in T.prompt_buckets(cell.mix):
+                    for g in range(lo - 1, hi):
+                        cap = cell.engine.capacity_for(P, g, tun)
+                        assert (tun, B, P, cap) in ex._warm, (B, P, g)
+    finally:
+        session.close()
 
 
 def test_no_chip_no_result():
