@@ -7,10 +7,16 @@ set-up spans start before 0.  ``load`` reads the ``kermit.*`` host events
 of a trace; ``reduce`` puts device busy time against them and names each
 idle gap by the innermost span of either kind (``bench.*`` or ``kermit.*``)
 over its middle, leaving the gaps themselves as ``trace.reduce`` finds
-them.  ``call_phases``, ``totals`` and ``span_check`` give the fact lines.
+them, and ``into`` puts that into the reduction the readers get.
+``call_phases``, ``totals`` and ``span_check`` give the fact lines.
+
+``run.py`` turns the program's spans on in a traced run only, before
+set-up, so that a run without the trace records nothing and its end-to-end
+numbers come from the path they always came from.
 """
 from __future__ import annotations
 
+import dataclasses
 import glob
 from dataclasses import dataclass
 
@@ -154,6 +160,16 @@ def reduce(ev: TR.Events, program: list) -> Reduced:
                 best = (n, b - a)
         gaps.append([best[0] if best else "none", (e - s) / 1e9])
     return Reduced(span_s, busy_s, gaps, inside)
+
+
+def into(trace: TR.Reduced, program: Reduced) -> TR.Reduced:
+    """The harness's reduction of a trace with the program's spans put in:
+    each span name's seconds and the device's busy seconds inside it, and
+    the idle gaps named by the innermost span of either kind."""
+    return dataclasses.replace(
+        trace, program_span_s=program.program_span_s,
+        busy_in_program_span_s=program.busy_in_program_span_s,
+        idle_gaps=program.idle_gaps)
 
 
 PHASES = ["t_dispatch_s", "prefill_dispatch_ms", "grow_ms", "prefill_wait_ms",

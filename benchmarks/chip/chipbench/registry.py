@@ -1,7 +1,14 @@
 """Find the benchmark's parts by name, each in a file of its own:
 
-  configs/<name>.json     a model configuration, with its source
+  configs/<name>.json     a model configuration, with its source, and a
+                          ``tiny`` block: the overrides of its ``program``
+                          block and its ``check`` limits that cut it to a
+                          cell the tests run on the CPU (``testing.py``;
+                          ``run.py`` never reads it)
   reference/<module>.py   the plain reference a configuration names
+  counts/<family>.py      the operations and bytes of a model family
+                          (``shapes.py``), by the ``program`` block's
+                          ``family``
   traffic/<mix>.json      a traffic mix
   cells/<cell>.json       a cell: configuration, mix, rate, loop settings
   metrics/<metric>.py     a per-layer metric: ``read(run) -> float | None``
@@ -69,6 +76,16 @@ def reference(name: str, root: str = ROOT):
 
 def metric(name: str, root: str = ROOT):
     return _module(root, "metrics", name)
+
+
+def counts(family: str, root: str = ROOT):
+    return _module(root, "counts", family)
+
+
+def config_names(root: str = ROOT) -> list:
+    """Every configuration file's name, sorted."""
+    return sorted(f[:-len(".json")] for f in os.listdir(
+        os.path.join(root, "configs")) if f.endswith(".json"))
 
 
 def benchmark(root: str = ROOT) -> dict:

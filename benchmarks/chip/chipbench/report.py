@@ -66,10 +66,13 @@ class RunView:
     tpot: np.ndarray         # per window request: its call's decode_s/steps
     probe_s: float           # executor measure seconds inside the window
     trace: object            # trace.Reduced, or None without --trace 1
-    shapes = shapes
+    spans: object            # program_spans.Spans on the window's clock,
+                             # or None where the program's spans were off
+    shapes: object           # chipbench.shapes bound to the run's counts
+                             # files (shapes.at)
 
 
-def view(run: dict, p: dict, pk: dict, trace) -> RunView:
+def view(run: dict, p: dict, pk: dict, trace, spans, root: str) -> RunView:
     seconds = run["seconds"]
     seg, win = run["seg"], run["window"]
     lo, hi = run["traced"] if trace is not None else (0.0, 0.0)
@@ -79,4 +82,5 @@ def view(run: dict, p: dict, pk: dict, trace) -> RunView:
                                  if lo <= c.t_dispatch < hi],
                    steps=[s for s in seg.steps if s.t_start < seconds],
                    prompt_len=win.prompt_len, gen=win.gen, tpot=seg.tpot,
-                   probe_s=run["probe_s"], trace=trace)
+                   probe_s=run["probe_s"], trace=trace, spans=spans,
+                   shapes=shapes.at(root))

@@ -1,9 +1,14 @@
 """Tiny cells for the benchmark's own tests on the CPU.
 
 ``tiny_root(path)`` lays out a folder shaped like ``benchmarks/chip/``: the
-real references, metric readers and traffic mixes, plus configurations cut
-to a few units of every width, a short mix, and a cell for each family.
-``run_tiny`` drives ``run.main`` over it with the look for a chip replaced.
+real references, counts, metric readers and traffic mixes, a short mix,
+and for every configuration file a tiny cell of that mix.  Each
+configuration is cut to a few units of every width by its own ``tiny``
+block (its name, the overrides of its ``program`` block and its ``check``
+limits), so a configuration added as a file gets its tiny cell, and the
+tests parametrised over ``TINY_CELLS`` run it, with no edit here.
+``run_tiny`` drives ``run.main`` over such a folder with the look for a
+chip replaced.
 """
 from __future__ import annotations
 
@@ -17,7 +22,35 @@ import shutil
 from chipbench import registry
 
 CHIP = registry.ROOT
-TINY_CELLS = ("tiny-qwen2.tinychat", "tiny-mamba2.tinychat")
+MIX = "tinychat"
+
+
+def tiny_config(config: dict) -> dict:
+    """A configuration cut by its ``tiny`` block: a nested group of the
+    ``program`` block (``ssm``) is updated key by key."""
+    t = config["tiny"]
+    out = {k: v for k, v in config.items() if k != "tiny"}
+    program = dict(config["program"], name=t["name"])
+    for k, v in t["program"].items():
+        program[k] = dict(program[k], **v) if isinstance(v, dict) else v
+    out.update(name=t["name"], program=program,
+               check=dict(config["check"], **t["check"]))
+    return out
+
+
+def tiny_configs(source: str = CHIP) -> list:
+    """Every configuration file under ``source``, cut by its ``tiny``
+    block, in the order of their names."""
+    return [tiny_config(registry.config(n, source))
+            for n in registry.config_names(source)]
+
+
+def tiny_cells(source: str = CHIP) -> tuple:
+    return tuple(f"{c['name']}.{MIX}" for c in tiny_configs(source))
+
+
+TINY_CONFIGS = tuple(c["name"] for c in tiny_configs())
+TINY_CELLS = tiny_cells()
 
 
 def load_run():
@@ -34,44 +67,34 @@ def _write(path, obj):
         json.dump(obj, f, indent=1)
 
 
-def tiny_root(path: str) -> dict:
-    """Fill ``path`` with tiny cells; return the BENCHMARK.json object."""
-    for kind in ("reference", "metrics", "traffic"):
-        shutil.copytree(os.path.join(CHIP, kind), os.path.join(path, kind))
+def tiny_root(path: str, source: str = CHIP) -> dict:
+    """Fill ``path`` with the tiny cells of the configuration files under
+    ``source``; return the BENCHMARK.json object that lists them."""
+    for kind in ("reference", "counts", "metrics", "traffic"):
+        shutil.copytree(os.path.join(source, kind), os.path.join(path, kind))
     for kind in ("configs", "cells"):
         os.makedirs(os.path.join(path, kind))
-    q = registry.config("qwen2-1.5b")
-    q["name"] = "tiny-qwen2"
-    q["program"].update(name="tiny-qwen2", n_layers=2, d_model=64, n_heads=4,
-                        n_kv_heads=2, d_ff=128, vocab=512, dtype="float32")
-    q["check"]["logit_gap"] = 1e-3
-    m = registry.config("mamba2-1.3b")
-    m["name"] = "tiny-mamba2"
-    m["program"].update(name="tiny-mamba2", n_layers=2, d_model=64,
-                        vocab=512, dtype="float32")
-    m["program"]["ssm"].update(d_state=16, head_dim=16, chunk=32)
-    m["check"]["logit_gap"] = 1e-3
-    for c in (q, m):
-        _write(os.path.join(path, "configs", c["name"] + ".json"), c)
-    _write(os.path.join(path, "traffic", "tinychat.json"), {
-        "name": "tinychat", "arrivals": "poisson",
+    _write(os.path.join(path, "traffic", MIX + ".json"), {
+        "name": MIX, "arrivals": "poisson",
         "phases": [{"name": "c", "rate_knee_share": 0.8,
                     "prompt_len": {"32": 0.5, "64": 0.5},
                     "output_len": {"median": 8, "sigma": 0.5, "min": 4,
                                    "max": 16}}]})
     bench = registry.benchmark()
     bench["workloads"] = []
-    for name in TINY_CELLS:
-        config, mix = name.split(".")
+    for c in tiny_configs(source):
+        _write(os.path.join(path, "configs", c["name"] + ".json"), c)
+        name = f"{c['name']}.{MIX}"
         _write(os.path.join(path, "cells", name + ".json"), {
-            "name": name, "config": config, "traffic": mix, "knee_rps": 20.0,
-            "initial_tunables": {"cache_len": 16},
+            "name": name, "config": c["name"], "traffic": MIX,
+            "knee_rps": 20.0, "initial_tunables": {"cache_len": 16},
             "plan_space": {"serve_batch": [2, 4], "cache_len": [16]},
             "kermit": {"window_size": 4, "analysis_interval": 3,
                        "min_windows": 3, "drift_eps": 0.45,
                        "warmup_windows": 8}})
-        bench["workloads"].append({"name": name, "config": config,
-                                   "traffic": mix, "chips": 1, "why": "test"})
+        bench["workloads"].append({"name": name, "config": c["name"],
+                                   "traffic": MIX, "chips": 1,
+                                   "why": "test"})
     return bench
 
 

@@ -154,6 +154,10 @@ class Reduced:
     busy_in_span_s: dict              # host span name -> device busy inside
     device_ops: list                  # [[name, seconds]] top 10
     idle_gaps: list                   # [[host span, seconds]] longest 10
+    # the program's own spans (``program_spans.into``), by name: seconds
+    # (merged) in the window, and device busy inside them
+    program_span_s: dict = field(default_factory=dict)
+    busy_in_program_span_s: dict = field(default_factory=dict)
 
 
 def reduce(ev: Events) -> Reduced:

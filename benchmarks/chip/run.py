@@ -7,11 +7,16 @@ From the root of a checkout, on a machine whose JAX devices are TPUs.  The
 cell, its configuration, its traffic mix and its per-layer metrics are
 files under ``benchmarks/chip/`` found by name (``chipbench/registry.py``);
 ``BENCHMARK.json`` at the root says which metrics the cell reports.
+With ``--trace 1`` the program's own spans (``repro.runtime.spans``) are
+on from before set-up, and the per-layer readers get them
+(``chipbench/program_spans.py``); with ``--trace 0`` they stay off.
 
 Earlier stdout lines are facts about the run (device, set-up and window
 compile seconds, the loop's decisions, the window's engine calls, the
 bytes in use between them, idle share of the whole window, host spans in
-the trace beside the host clock, the backlog at the close).  The stamped
+the trace beside the host clock, the backlog at the close; traced, the
+program's spans beside the engine's reports and the host clock, and where
+each engine call's host time went).  The stamped
 ``memory_peak_bytes`` is the process's peak, reached in set-up.  The last
 stderr lines give each number that decides ``correct`` beside its limit.
 The last stdout line is the result: ``correct``, ``attempted``,
@@ -78,10 +83,21 @@ def main(argv=None, *, root=None, bench=None, stamp=device_stamp,
     w = workload(bench, args.workload)
     device = stamp(int(w["chips"]))
     sys.path[:0] = [HERE, os.path.join(REPO, "src")]
+    from repro.runtime import spans
+    # a traced run records the program's own spans from before set-up; a
+    # run without the trace records none
+    log = spans.enable() if args.trace else None
+    try:
+        return measure(args, w, device, root, bench, corrupt, t_start, log)
+    finally:
+        if log is not None:
+            spans.disable()
+
+
+def measure(args, w, device, root, bench, corrupt, t_start, log) -> int:
     import jax
-    import numpy as np
-    from chipbench import check, registry, report, trace as TR
-    from chipbench import traffic as T
+    from chipbench import check, program_spans as PS, registry, report
+    from chipbench import trace as TR
     from chipbench.cell import Cell
     from repro.runtime.compile_cache import enable_compile_cache
     root = root or registry.ROOT
@@ -108,10 +124,12 @@ def main(argv=None, *, root=None, bench=None, stamp=device_stamp,
         device["memory_peak_bytes"] = int(max(
             (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
             for d in jax.local_devices()[:int(w["chips"])]))
-        reduced = None
+        reduced = program = None
         if trace_dir:
             t0 = time.perf_counter()
-            reduced = TR.reduce(TR.load_events(trace_dir))
+            events = TR.load_events(trace_dir)
+            program = PS.reduce(events, PS.load(trace_dir))
+            reduced = PS.into(TR.reduce(events), program)
             fact(trace_reduce_s=time.perf_counter() - t0)
     finally:
         if trace_dir:
@@ -122,11 +140,22 @@ def main(argv=None, *, root=None, bench=None, stamp=device_stamp,
     e2e = report.end_to_end(run)
     metrics = {}
     if args.trace:
-        view = report.view(run, cell.p, report.peak(device["kind"]), reduced)
+        book = log.to_json()
+        sp = PS.Spans(book, PS.align(book, [c.t_dispatch for c in
+                                            run["seg"].calls]),
+                      run["seconds"])
+        fact(program_spans={"recorded": len(book["spans"]),
+                            "dropped": book["dropped"],
+                            "check": PS.span_check(sp, run, program)})
+        fact(call_phases=PS.call_phases(sp))
+        view = report.view(run, cell.p, report.peak(device["kind"]), reduced,
+                           sp, root)
         device["busy_s"] = reduced.busy_s
         device["window_s"] = reduced.window_s
         fact(window_idle_share=1.0 - reduced.busy_s / reduced.window_s,
              span_s=reduced.span_s, busy_in_span_s=reduced.busy_in_span_s,
+             program_span_s=reduced.program_span_s,
+             busy_in_program_span_s=reduced.busy_in_program_span_s,
              program_s=reduced.program_s)
         fact(span_check=report.span_check(run, reduced))
         for m in bench["per_layer"]:

@@ -16,7 +16,7 @@ sys.path.insert(0, HERE)
 from chipbench import check, registry, testing  # noqa: E402
 from chipbench.cell import model_config  # noqa: E402
 
-FAMILIES = ("tiny-qwen2", "tiny-mamba2")
+FAMILIES = testing.TINY_CONFIGS
 
 
 @pytest.fixture(scope="module")

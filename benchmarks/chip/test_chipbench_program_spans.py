@@ -235,7 +235,7 @@ def test_tiny_run_with_the_spans_on(tmp_path, monkeypatch, no_compile_cache):
                         lambda run: runs.append(run) or e2e(run))
     log = spans.enable()
     try:
-        line, _, _ = testing.run_tiny(root, bench, testing.TINY_CELLS[0])
+        line, _, _ = testing.run_tiny(root, bench, "tiny-qwen2.tinychat")
     finally:
         spans.disable()
     assert line["correct"] is True
@@ -263,3 +263,73 @@ def test_tiny_run_with_the_spans_on(tmp_path, monkeypatch, no_compile_cache):
     setup = sp.totals(hi=0.0)
     assert setup["kermit.probe"]["count"] >= 1
     assert np.isfinite(setup["kermit.step_batch"]["self_s"])
+
+
+READERS = ("decode_idle_share", "engine_overhead_ms_per_call",
+           "setup_probe_s", "setup_manager_s")
+
+
+def test_traced_run_reads_the_program_spans(tmp_path, monkeypatch,
+                                            no_compile_cache):
+    """``--trace 1`` turns the program's spans on before set-up and off at
+    the end: the readers get them as ``RunView.spans`` and in the reduced
+    trace, and each of the four span readers returns a number.  The CPU
+    has no device plane, so one is planted: a program over the first half
+    of each ``kermit.decode`` span, which reads as half of the decode loop
+    idle."""
+    from chipbench import report
+    from repro.runtime import spans
+    root = str(tmp_path)
+    bench = testing.tiny_root(root)
+    bench["per_layer"] = [m for m in bench["per_layer"]
+                          if m["name"] in READERS] + [
+        {"name": n, "unit": "x", "better": "lower", "source": "program_span",
+         "layer": "Engine", "moves": "ttft_p90_s"}
+        for n in READERS if n not in {m["name"] for m in bench["per_layer"]}]
+    load = TR.load_events
+
+    def planted(trace_dir):
+        ev = load(trace_dir)
+        ev.device += [("jit_stand_in", s, (s + e) // 2, 0)
+                      for n, s, e in PS.load(trace_dir)
+                      if n == "kermit.decode"]
+        return ev
+    monkeypatch.setattr(TR, "load_events", planted)
+    monkeypatch.setattr(report, "peak", lambda kind: None)
+    views = []
+    make = report.view
+    monkeypatch.setattr(report, "view",
+                        lambda *a: views.append(make(*a)) or views[-1])
+    line, out, _ = testing.run_tiny(root, bench, "tiny-qwen2.tinychat",
+                                    trace=1)
+    assert spans._log is None                     # off again after the run
+    assert line["correct"] is True
+    view, = views
+    assert isinstance(view.spans, PS.Spans) and view.spans.seconds == 2.0
+    assert view.trace.program_span_s["kermit.decode"] > 0
+    assert set(line["metrics"]) == set(READERS)
+    assert all(np.isfinite(m["value"]) for m in line["metrics"].values())
+    assert line["metrics"]["decode_idle_share"]["value"] == pytest.approx(
+        50, abs=1)
+    assert line["metrics"]["setup_probe_s"]["value"] > 0
+    # set-up spans are in the log: the loop's search, and the warm-up
+    assert view.spans.named("kermit.probe", hi=0.0)
+    # the breakdown's gaps are named by the innermost span of either kind
+    assert line["breakdown"]["idle_gaps"] == view.trace.idle_gaps
+
+
+def test_untraced_run_records_no_spans(tmp_path, monkeypatch,
+                                       no_compile_cache):
+    from chipbench import report
+    from repro.runtime import spans
+    root = str(tmp_path)
+    bench = testing.tiny_root(root)
+    monkeypatch.setattr(spans, "enable", lambda *a: pytest.fail(
+        "spans enabled in a run without the trace"))
+    views = []
+    make = report.view
+    monkeypatch.setattr(report, "view",
+                        lambda *a: views.append(make(*a)) or views[-1])
+    line, _, _ = testing.run_tiny(root, bench, "tiny-qwen2.tinychat")
+    assert line["correct"] is True and views == []
+    assert spans._log is None

@@ -138,3 +138,82 @@ def test_added_parts_need_no_edit(tmp_path, monkeypatch):
     assert line["metrics"]["calls_served"]["value"] >= 1
     after = _digests(root)
     assert {p: after[p] for p in before} == before
+
+
+def test_every_configuration_has_a_tiny_cut():
+    """Each configuration file cuts itself to a tiny cell: a name of its
+    own, overrides of keys its program block has, and a limit for every
+    number compared."""
+    names = registry.config_names()
+    assert {c["name"] for c in BENCH["configs"]} <= set(names)
+    tiny = testing.tiny_configs()
+    assert len({c["name"] for c in tiny}) == len(names)
+    assert testing.TINY_CELLS == tuple(c["name"] + ".tinychat"
+                                       for c in tiny)
+    for n, t in zip(names, tiny):
+        cfg = registry.config(n)
+        assert NAME.match(t["name"]) and t["name"] not in names
+        assert set(cfg["tiny"]["program"]) <= set(cfg["program"])
+        for k, v in cfg["tiny"]["program"].items():
+            if isinstance(v, dict):
+                assert set(v) <= set(cfg["program"][k])
+        assert set(t["program"]) == set(cfg["program"])
+        for x in check.NUMBERS:
+            assert isinstance(t["check"][x], float) and t["check"][x] > 0
+        assert cfg["published_weight_bytes"] > 0
+        assert "tiny" not in t
+
+
+def test_a_configuration_added_as_files_runs_on_the_cpu(tmp_path,
+                                                        monkeypatch):
+    """A configuration file with its tiny block, a reference and the
+    counts file of its family, each added as a file, give a tiny cell that
+    runs through ``run.main`` correct, and not correct with its served
+    tokens altered, with no edit to a file that was there."""
+    import shutil
+    import jax
+    from chipbench import shapes
+    import repro.runtime.compile_cache as cc
+    monkeypatch.setattr(cc, "enable_compile_cache", lambda: "off")
+    src, out = tmp_path / "src", tmp_path / "out"
+    for kind in ("configs", "reference", "counts", "metrics", "traffic"):
+        shutil.copytree(os.path.join(HERE, kind), src / kind)
+    # a new family name, which the program serves as a decoder with
+    # attention, with a counts file and a reference of its own
+    shutil.copy(src / "counts" / "dense.py", src / "counts" / "toy.py")
+    shutil.copy(src / "reference" / "qwen2.py", src / "reference" / "toy.py")
+    cfg = registry.config("qwen2-1.5b")
+    cfg.update(name="toy-1b", reference="toy")
+    cfg["program"].update(name="toy-1b", family="toy", d_ff=4096)
+    cfg["tiny"].update(name="tiny-toy")
+    cfg["tiny"]["program"]["d_ff"] = 96
+    with open(src / "configs" / "toy-1b.json", "w") as f:
+        json.dump(cfg, f)
+    before = _digests(str(src))
+
+    assert "tiny-toy.tinychat" in testing.tiny_cells(str(src))
+    os.makedirs(out)
+    bench = testing.tiny_root(str(out), str(src))
+    assert "tiny-toy.tinychat" in {w["name"] for w in bench["workloads"]}
+    p = registry.config("tiny-toy", str(out))["program"]
+    ref = registry.reference("toy", str(out))
+    tree = jax.eval_shape(lambda: ref.make_params(p, jax.random.PRNGKey(0)))
+    assert shapes.at(str(out)).weight_bytes(p) == sum(
+        x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(tree))
+    line, _, _ = testing.run_tiny(str(out), bench, "tiny-toy.tinychat")
+    assert line["correct"] is True
+
+    def alter_token(engine):
+        serve = engine.serve
+
+        def altered(**kw):
+            rep = serve(**kw)
+            rep.generated = rep.generated.copy()
+            rep.generated[:, -1] = (rep.generated[:, -1] + 1) \
+                % engine.cfg.vocab
+            return rep
+        engine.serve = altered
+    line, _, _ = testing.run_tiny(str(out), bench, "tiny-toy.tinychat",
+                                  corrupt=alter_token)
+    assert line["correct"] is False
+    assert _digests(str(src)) == before

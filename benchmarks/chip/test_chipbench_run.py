@@ -59,7 +59,7 @@ def test_traced_line(tiny, monkeypatch):
     # no device metric is read on the CPU, so no peak is ever used
     monkeypatch.setattr(report, "peak", lambda kind: None)
     root, bench = tiny
-    line, out, _ = testing.run_tiny(root, bench, testing.TINY_CELLS[0],
+    line, out, _ = testing.run_tiny(root, bench, "tiny-qwen2.tinychat",
                                     trace=1)
     facts = {k: v for x in out.strip().splitlines()[:-1]
              for k, v in json.loads(x).items()}
@@ -68,9 +68,15 @@ def test_traced_line(tiny, monkeypatch):
     assert set(line["metrics"]) <= names
     # the CPU has no device plane: no device metric is read from it
     assert not {"prefill_mfu", "decode_mfu", "decode_roofline",
-                "engine_idle_share"} & set(line["metrics"])
+                "engine_idle_share", "decode_idle_share"} & set(
+                    line["metrics"])
     assert {"prefill_ms_per_ktok", "decode_step_ms", "tpot_p90_s",
             "probe_s", "manager_self_s_per_window"} <= set(line["metrics"])
+    # the program's spans, on in a traced run, are read
+    assert {"engine_overhead_ms_per_call", "setup_probe_s",
+            "setup_manager_s"} & names <= set(line["metrics"])
+    assert facts["program_spans"]["dropped"] == 0
+    assert len(facts["call_phases"]["rows"]) == len(facts["calls"]["rows"])
     assert line["device"]["window_s"] > 0
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
     assert list(line)[-1] == "checks"
